@@ -28,7 +28,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.report import Finding
 

@@ -1,4 +1,6 @@
 """Config registry: --arch <id> resolution."""
+import dataclasses
+
 from repro.configs.base import ArchConfig, ShapeConfig, SHAPES, \
     LONG_CONTEXT_OK  # noqa: F401
 
@@ -18,8 +20,12 @@ _REGISTRY = {
 ARCH_NAMES = tuple(_REGISTRY)
 
 
-def get_config(name: str, smoke: bool = False) -> ArchConfig:
+def get_config(name: str, smoke: bool = False,
+               layers: int = 0) -> ArchConfig:
+    """The published (or `smoke`) config of `name`.  `layers` > 0 cuts
+    the depth to that many layers and keeps every width."""
     if name not in _REGISTRY:
         raise KeyError(f"unknown arch {name!r}; have {ARCH_NAMES}")
     mod = _REGISTRY[name]
-    return mod.SMOKE if smoke else mod.CONFIG
+    cfg = mod.SMOKE if smoke else mod.CONFIG
+    return dataclasses.replace(cfg, n_layers=layers) if layers else cfg

@@ -87,8 +87,10 @@ def _hash_uniform(idx: jax.Array, seed) -> jax.Array:
     x = (x ^ (x >> 16)) * jnp.uint32(0x85EBCA6B)
     x = (x ^ s ^ (x >> 13)) * jnp.uint32(0xC2B2AE35)
     x = x ^ (x >> 16)
-    # 24-bit mantissa -> [0, 1)
-    return (x >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # 24-bit mantissa -> [0, 1); through int32 because Mosaic has no
+    # uint32 -> f32 cast (exact: the value is < 2^24)
+    return ((x >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def _tile_mask_vals(s_tile, seed, off, tau, *, row0, col0,
@@ -110,8 +112,8 @@ def _tile_mask(s_ref, seed_ref, off_ref, tau_ref, *, row0, col0,
                bk: int, bn: int, n_total: int, mode: str):
     """Bernoulli (hash-stream) or threshold mask for one (bk, bn) tile."""
     del bk, bn  # implied by the ref block shape
-    return _tile_mask_vals(s_ref[...], seed_ref[0], off_ref[0],
-                           tau_ref[0], row0=row0, col0=col0,
+    return _tile_mask_vals(s_ref[...], seed_ref[0, 0], off_ref[0, 0],
+                           tau_ref[0, 0], row0=row0, col0=col0,
                            n_total=n_total, mode=mode)
 
 
@@ -139,12 +141,17 @@ def _kernel(x_ref, w_ref, s_ref, seed_ref, off_ref, tau_ref, o_ref,
 
 
 def _scalar_operands(seed, off, tau):
-    return (jnp.asarray(seed, jnp.uint32).reshape(1),
-            jnp.asarray(off, jnp.uint32).reshape(1),
-            jnp.asarray(tau, jnp.float32).reshape(1))
+    return (jnp.asarray(seed, jnp.uint32).reshape(1, 1),
+            jnp.asarray(off, jnp.uint32).reshape(1, 1),
+            jnp.asarray(tau, jnp.float32).reshape(1, 1))
 
 
-_SCALAR_SPECS = [pl.BlockSpec((1,), lambda i, j, k: (0,))] * 3
+# Scalar operands live whole in SMEM as (1, n) arrays.  The leading unit
+# axis is what keeps them legal under `vmap` (the cohort axis of the
+# train step): batching prepends a squeezed block dim, and Mosaic only
+# accepts that when the trailing two block dims equal the array's.
+_SCALAR_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+_SCALAR_SPECS = [_SCALAR_SPEC] * 3
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
@@ -330,30 +337,39 @@ def masked_matmul_ds(x: jax.Array, g: jax.Array, w: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _or_lanes(m, axis: int):
+    """Pack {0,1} bits along `axis` (bit j from lane j) into uint32 words.
+    The bits are disjoint, so their int32 sum equals their OR; Mosaic
+    reduces int32 but not uint32, and the bitcast restores bit 31."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, m.shape, axis)
+    words = jnp.sum(m.astype(jnp.int32) << lanes, axis=axis)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
 def _sap_kernel(s_ref, seed_ref, o_ref, *, bw: int, n_total: int,
                 mode: str, tau: float):
-    i = pl.program_id(1)
-    # word/lane coordinates of this (1, bw, 32) tile; bit j of word wi
+    c, i = pl.program_id(0), pl.program_id(1)
+    # word/lane coordinates of this (bw, 32) tile; bit j of word wi
     # carries flat element wi*32 + j (little-endian, matching pack_bits)
-    words = i * bw + jax.lax.broadcasted_iota(jnp.uint32, (1, bw, 32), 1)
-    lanes = jax.lax.broadcasted_iota(jnp.uint32, (1, bw, 32), 2)
-    idx = (words * jnp.uint32(32) + lanes).astype(jnp.uint32)
+    words = (i * bw).astype(jnp.uint32) + jax.lax.broadcasted_iota(
+        jnp.uint32, (bw, 32), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.uint32, (bw, 32), 1)
+    idx = words * jnp.uint32(32) + lanes
 
     theta = jax.nn.sigmoid(s_ref[...].astype(jnp.float32))
     if mode == "threshold":
         m = theta > jnp.float32(tau)
     else:
-        m = _hash_uniform(idx, seed_ref[0]) < theta
+        m = _hash_uniform(idx, seed_ref[0, c]) < theta
     # padding bits (idx >= n_total) are forced to zero so the packed
     # words match pack_bits(pad_to_words(mask)) exactly
     m = m & (idx < jnp.uint32(n_total))
-    bits = m.astype(jnp.uint32) << lanes
-    o_ref[...] = jnp.sum(bits, axis=2).astype(jnp.uint32)
+    o_ref[...] = _or_lanes(m, axis=1)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "interpret", "mode",
                                              "tau"))
-def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 256,
+def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 512,
                     interpret: bool = False, mode: str = "sample",
                     tau: float = 0.5) -> jax.Array:
     """s: (C, n) score rows; seeds: (C,) uint32 per-row stream seeds.
@@ -365,17 +381,18 @@ def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 256,
     C, n = s.shape
     assert seeds.shape == (C,), (seeds.shape, C)
     W = (n + 31) // 32
-    # prefer a block that divides W exactly: real leaves (dims multiples
-    # of 8) give highly composite W, so no score-sized pad copy is made;
-    # only degenerate W (no divisor >= 8) falls back to rounding W up,
-    # where the jnp.pad copy is cheaper than a near-unit-block grid
-    b = min(bw, W)
-    while W % b:
-        b //= 2
-    if b >= 8 or b == W:
-        bw_, Wp = b, W
+    # the word axis is the lane axis of the output block, so a block is
+    # either the whole row or a multiple of 128 words.  Real leaves (dims
+    # multiples of 128) give W divisible by the block and no pad copy;
+    # only a ragged long row is rounded up to whole blocks.
+    if W <= bw:
+        bw_, Wp = W, W
     else:
-        bw_ = min(bw, W)
+        bw_ = bw
+        while W % bw_ and bw_ > 128:
+            bw_ //= 2
+        if W % bw_:
+            bw_ = bw
         Wp = -(-W // bw_) * bw_
     pad = Wp * 32 - n
     sp = jnp.pad(s, ((0, 0), (0, pad))) if pad else s
@@ -386,13 +403,14 @@ def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 256,
         kernel,
         grid=(C, Wp // bw_),
         in_specs=[
-            pl.BlockSpec((1, bw_, 32), lambda c, i: (c, i, 0)),
-            pl.BlockSpec((1,), lambda c, i: (c,)),
+            pl.BlockSpec((None, bw_, 32), lambda c, i: (c, i, 0)),
+            _SCALAR_SPEC,
         ],
-        out_specs=pl.BlockSpec((1, bw_), lambda c, i: (c, i)),
-        out_shape=jax.ShapeDtypeStruct((C, Wp), jnp.uint32),
+        out_specs=pl.BlockSpec((None, 1, bw_), lambda c, i: (c, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((C, 1, Wp), jnp.uint32),
         interpret=interpret,
-    )(s3, jnp.asarray(seeds, jnp.uint32))
+    )(s3, jnp.asarray(seeds, jnp.uint32).reshape(1, C))
+    out = out.reshape(C, Wp)
     return out[:, :W]
 
 
@@ -409,9 +427,9 @@ def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 256,
 
 
 def _grp_operands(seeds, offs, tau):
-    return (jnp.asarray(seeds, jnp.uint32).reshape(-1),
-            jnp.asarray(offs, jnp.uint32).reshape(-1),
-            jnp.asarray(tau, jnp.float32).reshape(1))
+    return (jnp.asarray(seeds, jnp.uint32).reshape(1, -1),
+            jnp.asarray(offs, jnp.uint32).reshape(1, -1),
+            jnp.asarray(tau, jnp.float32).reshape(1, 1))
 
 
 def _g_kernel(x_ref, w_ref, s_ref, seed_ref, off_ref, tau_ref, o_ref,
@@ -423,8 +441,9 @@ def _g_kernel(x_ref, w_ref, s_ref, seed_ref, off_ref, tau_ref, o_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    n_i = pl.program_id(2)
-    m = _tile_mask_vals(s_ref[0], seed_ref[0], off_ref[0], tau_ref[0],
+    e, n_i = pl.program_id(0), pl.program_id(2)
+    m = _tile_mask_vals(s_ref[0], seed_ref[0, e], off_ref[0, e],
+                        tau_ref[0, 0],
                         row0=k_i * jnp.uint32(bk),
                         col0=n_i * jnp.uint32(bn),
                         n_total=n_total, mode=mode)
@@ -471,10 +490,7 @@ def masked_matmul_grouped(x: jax.Array, w: jax.Array, s: jax.Array,
             pl.BlockSpec((1, bm_, bk_), lambda e, i, j, k: (e, i, k)),
             pl.BlockSpec((1, bk_, bn_), lambda e, i, j, k: (e, k, j)),
             pl.BlockSpec((1, bk_, bn_), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1,), lambda e, i, j, k: (e,)),
-            pl.BlockSpec((1,), lambda e, i, j, k: (e,)),
-            pl.BlockSpec((1,), lambda e, i, j, k: (0,)),
-        ],
+        ] + _SCALAR_SPECS,
         out_specs=pl.BlockSpec((1, bm_, bn_), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
@@ -491,8 +507,9 @@ def _g_dx_kernel(g_ref, w_ref, s_ref, seed_ref, off_ref, tau_ref, o_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    k_i = pl.program_id(2)
-    m = _tile_mask_vals(s_ref[0], seed_ref[0], off_ref[0], tau_ref[0],
+    e, k_i = pl.program_id(0), pl.program_id(2)
+    m = _tile_mask_vals(s_ref[0], seed_ref[0, e], off_ref[0, e],
+                        tau_ref[0, 0],
                         row0=k_i * jnp.uint32(bk),
                         col0=n_i * jnp.uint32(bn),
                         n_total=n_total, mode=mode)
@@ -538,10 +555,7 @@ def masked_matmul_grouped_dx(g: jax.Array, w: jax.Array, s: jax.Array,
             pl.BlockSpec((1, bm_, bn_), lambda e, i, k, n: (e, i, n)),
             pl.BlockSpec((1, bk_, bn_), lambda e, i, k, n: (e, k, n)),
             pl.BlockSpec((1, bk_, bn_), lambda e, i, k, n: (e, k, n)),
-            pl.BlockSpec((1,), lambda e, i, k, n: (e,)),
-            pl.BlockSpec((1,), lambda e, i, k, n: (e,)),
-            pl.BlockSpec((1,), lambda e, i, k, n: (0,)),
-        ],
+        ] + _SCALAR_SPECS,
         out_specs=pl.BlockSpec((1, bm_, bk_), lambda e, i, k, n: (e, i, k)),
         out_shape=jax.ShapeDtypeStruct((E, M, K), g.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bk_), jnp.float32)],
@@ -629,8 +643,8 @@ def _conv_kernel(x_ref, w_ref, s_ref, seed_ref, off_ref, tau_ref, o_ref,
     else:
         j = pl.program_id(1)
         bc = w_ref.shape[-1]
-        m = _tile_mask_vals(s_ref[...], seed_ref[0], off_ref[0],
-                            tau_ref[0], row0=jnp.uint32(0),
+        m = _tile_mask_vals(s_ref[...], seed_ref[0, 0], off_ref[0, 0],
+                            tau_ref[0, 0], row0=jnp.uint32(0),
                             col0=j * jnp.uint32(bc),
                             n_total=n_total, mode=mode)
         wm = jnp.where(m, w_ref[...].astype(jnp.float32), 0.0)
@@ -673,7 +687,7 @@ def masked_conv1d(x_pad: jax.Array, w: jax.Array, s: jax.Array,
             pl.BlockSpec((1, Sp, bc_), lambda b, j: (b, 0, j)),
             pl.BlockSpec((Wt, bc_), lambda b, j: (0, j)),
             pl.BlockSpec((Wt, bc_), lambda b, j: (0, j)),
-        ] + [pl.BlockSpec((1,), lambda b, j: (0,))] * 3,
+        ] + _SCALAR_SPECS,
         out_specs=pl.BlockSpec((1, S, bc_), lambda b, j: (b, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.float32),
         interpret=interpret,

@@ -41,12 +41,14 @@ bitpack (scores -> hash -> Bernoulli -> uint32 words in one pass).
 
 Environment knobs (documented in README "Execution paths"):
   * REPRO_REF_BWD=1        — naive jnp STE backward (debug baseline)
-  * REPRO_FORCE_INTERPRET=1 — pin Pallas interpret mode (CI determinism)
+  * REPRO_FORCE_INTERPRET=1 — pin Pallas interpret mode on any backend
   * REPRO_EFF_PATH=1       — read by repro.launch.steps: train through
     materialized effective params instead of the fused kernels
 
-On non-TPU backends (this CPU container) the wrappers call the kernels
-in interpret mode — selected once per process by `_use_interpret()`.
+On the CPU backend the wrappers call the kernels in interpret mode —
+selected once per process by `_use_interpret()`.  Every other backend
+compiles them with Mosaic: there is no silent fallback, so a backend
+the kernels cannot compile for fails loudly instead of being emulated.
 """
 from __future__ import annotations
 
@@ -67,13 +69,13 @@ def repro_backend() -> str:
 
 @functools.lru_cache(maxsize=1)
 def _use_interpret() -> bool:
-    """Cached per process: `jax.default_backend()` walks the backend
-    registry, which is pure overhead when re-queried inside every jit
-    trace.  `REPRO_FORCE_INTERPRET=1` pins interpret mode regardless of
-    backend (CI determinism)."""
+    """Interpret the kernels on the CPU backend only.  Cached per
+    process: `jax.default_backend()` walks the backend registry, which
+    is pure overhead when re-queried inside every jit trace.
+    `REPRO_FORCE_INTERPRET=1` pins interpret mode on any backend."""
     if os.environ.get("REPRO_FORCE_INTERPRET", "") == "1":
         return True
-    return repro_backend() != "tpu"
+    return repro_backend() == "cpu"
 
 
 def reset_backend_cache() -> None:

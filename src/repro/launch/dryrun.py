@@ -189,10 +189,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
     t0 = time.time()
     results = {}
-    # jax>=0.6 spells the context manager jax.set_mesh; on older
-    # wheels Mesh is itself a context manager
-    set_mesh = getattr(jax, "set_mesh", lambda m: m)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape_cfg.kind == "train":
             state_shapes = jax.eval_shape(
                 lambda k: steplib.init_fed_state(k, api, spec, C), key)
@@ -298,8 +295,6 @@ def _analyze(lowered, keep_hlo=False):
 def _analyze_compiled(compiled, keep_hlo=False):
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):        # older jax: one dict per program
-        cost = cost[0] if cost else None
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     out = {

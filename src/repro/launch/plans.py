@@ -28,6 +28,7 @@ class LaunchPlan:
     state: Any
     step_fn: Callable                 # (state, batch) -> (state, metrics)
     round_fn: Optional[Callable]      # (state) -> (state, metrics) | None
+    #                                   both donate `state`
     make_batch: Callable              # (key, tokens, batch, seq) -> batch
 
 
@@ -62,11 +63,16 @@ def _mask_plan(name, *, force_lam=None, mask_mode=None):
         spec = masking.MaskSpec() if spec is None else spec
         state = steplib.init_fed_state(key, model_api, spec, C=cohorts,
                                        optimizer=optimizer)
+        # both steps donate the state: the old and the new one are
+        # never live together, which is what lets a full-width model
+        # fit one chip
         return LaunchPlan(
             name=name, state=state,
-            step_fn=jax.jit(steplib.make_train_step(model_api, scfg)),
+            step_fn=jax.jit(steplib.make_train_step(model_api, scfg),
+                            donate_argnums=0),
             round_fn=jax.jit(steplib.make_round_step(model_api, scfg,
-                                                     codec=codec)),
+                                                     codec=codec),
+                             donate_argnums=0),
             make_batch=_cohort_batch(cohorts))
     return plan
 
@@ -77,7 +83,8 @@ def _fedavg_plan(model_api, scfg: steplib.StepConfig, *, key, cohorts,
     state = steplib.init_fedavg_state(key, model_api)
     return LaunchPlan(
         name="fedavg", state=state,
-        step_fn=jax.jit(steplib.make_fedavg_step(model_api, scfg)),
+        step_fn=jax.jit(steplib.make_fedavg_step(model_api, scfg),
+                        donate_argnums=0),
         round_fn=None, make_batch=_flat_batch)
 
 

@@ -35,6 +35,7 @@ from repro.configs import get_config
 from repro.core import masking
 from repro.models import build_model
 from repro.launch import steps as steplib
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _serve_single(args, cfg, api, key, mp):
@@ -60,6 +61,7 @@ def _serve_single(args, cfg, api, key, mp):
 
     tok = prompt[:, 0]
     prefill_s = decode_s = 0.0
+    generated = []
     for t in range(S - 1):
         t0 = time.perf_counter()
         logits, cache = serve(eff, cache, tok, jnp.asarray(t, jnp.int32))
@@ -71,12 +73,15 @@ def _serve_single(args, cfg, api, key, mp):
         else:
             decode_s += dt
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            generated.append(tok)
     pre_tok = B * (P - 1)
     dec_tok = B * args.tokens
     print(f"{cfg.name}: {B} requests, prefill {pre_tok} tok in "
           f"{prefill_s:.3f}s ({pre_tok / max(prefill_s, 1e-9):.1f} tok/s), "
           f"decode {dec_tok} tok in {decode_s:.3f}s "
           f"({dec_tok / max(decode_s, 1e-9):.1f} tok/s)")
+    return {"tokens": jnp.stack(generated, axis=1), "last_logits": logits,
+            "prefill_s": prefill_s, "decode_s": decode_s}
 
 
 def _serve_multi(args, cfg, api, key, mp):
@@ -111,12 +116,19 @@ def _serve_multi(args, cfg, api, key, mp):
           f"({st['delta_bytes_per_tree']} B) = {st['resident_bytes']} B "
           f"for {st['tenants']} tenants "
           f"(mask artifact {st['mask_artifact_bytes']} B/tenant)")
+    return {"completions": done, "stats": st}
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Run the launcher; returns the generated tokens and the logits
+    that chose them (single tenant: ``tokens``/``last_logits``;
+    several: the engine's ``completions`` and ``stats``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-4b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers at published widths "
+                         "(0 = the config's own depth)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--tokens", type=int, default=16)
@@ -135,7 +147,8 @@ def main(argv=None):
                          "(throughput mode; not bit-exact)")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke, layers=args.layers)
     api = build_model(cfg)
     # --seed picks the frozen random network (the artifact's RNG seed);
     # the deployed threshold mask is deterministic given the scores
@@ -143,9 +156,8 @@ def main(argv=None):
     mp = masking.init_masked(key, api.init_params(key),
                              masking.MaskSpec())
     if args.tenants > 1:
-        _serve_multi(args, cfg, api, key, mp)
-    else:
-        _serve_single(args, cfg, api, key, mp)
+        return _serve_multi(args, cfg, api, key, mp)
+    return _serve_single(args, cfg, api, key, mp)
 
 
 if __name__ == "__main__":

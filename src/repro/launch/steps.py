@@ -48,22 +48,6 @@ from repro.launch import sharding as shd
 Pytree = Any
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of jax.experimental after 0.4.x and the
-    check_rep kwarg was later renamed check_vma; both moves happened in
-    different releases, so resolve home and kwarg name independently."""
-    import inspect
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    kw = ("check_vma"
-          if "check_vma" in inspect.signature(sm).parameters
-          else "check_rep")
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: False})
-
-
 def n_cohorts(mesh) -> int:
     return mesh.shape["pod"] if "pod" in mesh.axis_names else 1
 
@@ -596,16 +580,16 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
                  specs_of(state_sh["opt_m"]),
                  jax.sharding.PartitionSpec(),
                  jax.sharding.PartitionSpec())
-    mapped = _shard_map(_round_local, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs)
+    mapped = jax.shard_map(_round_local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     # participation variant: the vector is replicated (every shard
     # slices out its own cohorts); traced separately so the no-fault
     # path stays byte-identical to the original lowering
-    mapped_part = _shard_map(
+    mapped_part = jax.shard_map(
         lambda sc, fl, w, om, st, pt: _round_local(sc, fl, w, om, st,
                                                    pt),
         mesh=mesh, in_specs=in_specs + (jax.sharding.PartitionSpec(),),
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
 
     def round_step(state, participation=None):
         part = _as_part(participation)

@@ -3,8 +3,9 @@
     python -m repro.launch.train --arch internlm2-1.8b --smoke \
         --steps 100 --round-every 10 --ckpt-dir /tmp/ckpt
 
-On real hardware the same entry point runs the production mesh; on this
-container use --smoke (reduced config, 1 device). Handles:
+On a CPU-only host use --smoke (reduced config, 1 device); on one TPU
+chip, --layers N keeps the published widths and cuts the depth to what
+fits (chip_smoke.py runs internlm2-1.8b at 4 layers). Handles:
   * checkpoint/restart (atomic, async)
   * round-boundary mask exchange (the paper's protocol)
   * elastic re-entry: --cohorts may differ across restarts; theta is
@@ -32,14 +33,22 @@ from repro.data import synthetic
 from repro.launch import steps as steplib
 from repro.launch import plans as planlib  # noqa: F401  (registers plans)
 from repro.launch import mesh as meshlib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import elastic, fault
 from repro import ckpt as ckptlib
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Run the launcher; returns the last step's and last round's
+    metrics with wall times (``first_step_s`` includes nothing but the
+    first step: the step program is compiled ahead, in ``compile_s``)
+    and the compiled step program itself (``compiled_step``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers at published widths "
+                         "(0 = the config's own depth)")
     ap.add_argument("--algo", default="fedpm_reg",
                     choices=list(fedapi.launchable()))
     ap.add_argument("--codec", default="arithmetic",
@@ -89,7 +98,8 @@ def main(argv=None):
     if args.agg_fault_prob > 0 and args.tree_fanout <= 0:
         ap.error("--agg-fault-prob requires --tree-fanout > 0")
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke, layers=args.layers)
     api = build_model(cfg)
     key = jax.random.PRNGKey(args.seed)
     scfg = steplib.StepConfig(lam=args.lam, lr=args.lr,
@@ -171,11 +181,24 @@ def main(argv=None):
         print(f"resumed ledger: {ledger.total_mb:.2f}MB over "
               f"{ledger.rounds} rounds")
 
+    # the step program is compiled ahead of the loop, so compile time
+    # and step time are reported apart
+    batch = plan.make_batch(jax.random.fold_in(key, start), toks,
+                            args.batch, args.seq)
+    t0 = time.perf_counter()
+    step_exec = step_fn.lower(state, batch).compile()
+    out = {"compile_s": time.perf_counter() - t0,
+           "compiled_step": step_exec, "rounds": 0}
+    step_times, round_times = [], []
     t0 = time.time()
     for step in range(start, args.steps):
-        kd = jax.random.fold_in(key, step)
-        batch = plan.make_batch(kd, toks, args.batch, args.seq)
-        state, m = step_fn(state, batch)
+        if step > start:
+            batch = plan.make_batch(jax.random.fold_in(key, step), toks,
+                                    args.batch, args.seq)
+        ts = time.perf_counter()
+        state, m = step_exec(state, batch)
+        out["loss"] = float(m["loss"])
+        step_times.append(time.perf_counter() - ts)
         if round_fn is not None and (step + 1) % args.round_every == 0:
             # draws are keyed by (seed, round index), NOT a mutable
             # generator cursor: a resumed run replays the identical
@@ -193,17 +216,23 @@ def main(argv=None):
                 alive = masked if masked.any() else base
             # survivor-renormalized aggregation: the participation
             # vector gates which cohorts' masks the round folds
+            ts = time.perf_counter()
             state, rm = (round_fn(state) if alive is None
                          else round_fn(state, jnp.asarray(alive)))
+            rm = {k: float(v) for k, v in rm.items()}
+            round_times.append(time.perf_counter() - ts)
+            out.update(rounds=out["rounds"] + 1, uplink_bpp=rm["bpp"],
+                       bpp_measured=rm["bpp_measured"],
+                       bits_measured=rm["bits_measured"])
             upd = {"uplink_bits_measured": rm["bits_measured"],
                    "downlink_bits": rm["downlink_bits"]}
             if topo is not None:
                 upd["root_bits_measured"] = float(
                     topo.surviving_edges(round_idx) * tree_edge_bits)
             ledger.update(upd)
-            msg = (f"step {step+1}: loss={float(m['loss']):.3f} "
-                   f"uplink={float(rm['bpp']):.3f}Bpp "
-                   f"(wire {float(rm['bpp_measured']):.3f}Bpp "
+            msg = (f"step {step+1}: loss={out['loss']:.3f} "
+                   f"uplink={rm['bpp']:.3f}Bpp "
+                   f"(wire {rm['bpp_measured']:.3f}Bpp "
                    f"{args.codec}) cum={ledger.total_mb:.2f}MB")
             if alive is not None:
                 msg += f" alive={alive.sum()}/{args.cohorts}"
@@ -222,8 +251,14 @@ def main(argv=None):
                                "rounds": ledger.rounds}, f)
                 os.replace(tmp, ledger_path)
         elif (step + 1) % 10 == 0:
-            print(f"step {step+1}: loss={float(m['loss']):.3f}",
-                  flush=True)
+            print(f"step {step+1}: loss={out['loss']:.3f}", flush=True)
+    # wall times in seconds: the first call of each program apart from
+    # the mean of the later ones (the first round also compiles)
+    for name, ts in (("step", step_times), ("round", round_times)):
+        if ts:
+            out[f"first_{name}_s"] = ts[0]
+        if len(ts) > 1:
+            out[f"{name}_s"] = sum(ts[1:]) / len(ts[1:])
     if saver:
         saver.close()
     if ledger.rounds:
@@ -235,6 +270,7 @@ def main(argv=None):
             msg += f" root={ledger.root_mb:.3f}MB"
         print(msg)
     print("done")
+    return out
 
 
 if __name__ == "__main__":

@@ -176,12 +176,16 @@ class AsyncRoundEngine:
 
         self._client_phase = jax.jit(client_phase)
 
-        def agg_phase(state_, batched, sizes_, staleness, part):
+        def agg_phase(state_, batched, sizes_, staleness, part, metrics):
             wn = aggregation.staleness_weights(
                 sizes_, staleness, self.config.staleness_alpha)
             new_state = self.algo.aggregate(state_, batched, wn, part)
             bpps = jax.vmap(lambda p: p.bpp())(batched)
-            return new_state, jnp.sum(bpps * wn), wn
+            # the weighted client metrics reduce inside this jit, as
+            # run_round reduces them inside its own: an eager sum
+            # rounds differently
+            return (new_state, jnp.sum(bpps * wn),
+                    {k: jnp.sum(v * wn) for k, v in metrics.items()})
 
         self._agg_phase = jax.jit(agg_phase)
 
@@ -364,8 +368,11 @@ class AsyncRoundEngine:
         stal = jnp.asarray([self.version - e.version for e in entries],
                            jnp.float32)
         part = jnp.ones((B,), bool)
-        self.state, up_bpp, wn = self._agg_phase(
-            self.state, batched, sizes, stal, part)
+        metrics = {k: jnp.asarray([e.metrics[k] for e in entries],
+                                  jnp.float32)
+                   for k in entries[0].metrics}
+        self.state, up_bpp, folded = self._agg_phase(
+            self.state, batched, sizes, stal, part, metrics)
         stal_max = int(max(self.version - e.version for e in entries))
         self.version += 1
         self.last_commit_tick = t
@@ -379,10 +386,7 @@ class AsyncRoundEngine:
                "staleness_max": stal_max,
                "clients": [e.client for e in entries]}
         out.update({k: self._since_commit[k] for k in self._since_commit})
-        for k in entries[0].metrics:
-            vals = jnp.asarray([e.metrics[k] for e in entries],
-                               jnp.float32)
-            out[k] = float(jnp.sum(vals * wn))
+        out.update({k: float(v) for k, v in folded.items()})
         self._since_commit = {k: 0.0 for k in self._since_commit}
         self._event("commit", version=self.version, folded=B,
                     forced=bool(forced))

@@ -9,9 +9,12 @@ the rule fires — a rule with no firing fixture is a dead gate.
 import jax
 import jax.numpy as jnp
 
-from repro.launch.steps import _shard_map
-
 P = jax.sharding.PartitionSpec
+
+
+def _shard_map(fn, mesh, in_specs, out_specs):
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def f32_score_all_gather(mesh):

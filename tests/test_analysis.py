@@ -90,8 +90,7 @@ def test_dtype_promotion_rule_bf16_upcast():
 
 
 def test_dtype_promotion_rule_f64():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) + 1.0)(jnp.ones((4,)))
     found = jaxpr_lint.lint_jaxpr(

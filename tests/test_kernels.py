@@ -581,13 +581,25 @@ def test_use_interpret_cached_and_forceable(monkeypatch,
     monkeypatch.delenv("REPRO_FORCE_INTERPRET")
     assert ops._use_interpret() is True
     assert ops._use_interpret.cache_info().hits >= 1
-    # ...until the public reset makes the flip take effect (on any
-    # non-TPU test backend the uncached answer is interpret=True, so
-    # flip via the backend probe instead)
+    # ...until the public reset makes the flip take effect (on the CPU
+    # test backend the uncached answer is interpret=True, so flip via
+    # the backend probe instead)
     monkeypatch.setattr(ops, "repro_backend", lambda: "tpu")
     assert ops._use_interpret() is True      # still the stale cache
     ops.reset_backend_cache()
     assert ops._use_interpret() is False     # fresh decision
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", False)])
+def test_use_interpret_only_on_cpu(monkeypatch, kernel_backend_reset,
+                                   backend, interpret):
+    """Interpret mode is chosen for the CPU backend only: any other
+    backend compiles the kernels, so a device they cannot compile for
+    fails instead of being emulated."""
+    monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
+    monkeypatch.setattr(ops, "repro_backend", lambda: backend)
+    assert ops._use_interpret() is interpret
 
 
 def test_hash_uniform_distribution():

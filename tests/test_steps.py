@@ -1,6 +1,7 @@
 """Lowered-step tests on a tiny debug mesh (1 device): the production
 train/round/serve steps must run end-to-end on CPU with real values."""
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -320,55 +321,69 @@ def test_train_step_adam_scores():
 
 
 # ---------------------------------------------------------------------------
-# the _shard_map compat shim: both homes, both kwarg spellings
+# jax.shard_map over the pod axis (the round step's collective home)
 # ---------------------------------------------------------------------------
 
 
-def test_shard_map_shim_prefers_jax_namespace(monkeypatch):
-    """When jax.shard_map exists (jax >= 0.6) the shim must use it and
-    probe the kwarg name from ITS signature — here the new check_vma
-    spelling."""
-    seen = {}
-
-    def fake_sm(fn, mesh=None, in_specs=None, out_specs=None,
-                check_vma=True):
-        seen.update(mesh=mesh, check_vma=check_vma)
-        return fn
-
-    monkeypatch.setattr(jax, "shard_map", fake_sm, raising=False)
+def test_shard_map_pod_psum_executes():
+    """The round step maps its body with jax.shard_map(check_vma=False);
+    a psum over the pod axis through it executes on the forced mesh."""
     mesh = meshlib.make_debug_pod_mesh()
     P = jax.sharding.PartitionSpec
-    out = steplib._shard_map(lambda x: x, mesh, (P(),), P())
-    assert seen == {"mesh": mesh, "check_vma": False}
-    assert out(3) == 3
-
-
-def test_shard_map_shim_old_kwarg_spelling(monkeypatch):
-    """A jax.shard_map that still spells the kwarg check_rep must get
-    check_rep=False, not an unexpected-kwarg TypeError."""
-    seen = {}
-
-    def fake_sm(fn, mesh=None, in_specs=None, out_specs=None,
-                check_rep=True):
-        seen.update(check_rep=check_rep)
-        return fn
-
-    monkeypatch.setattr(jax, "shard_map", fake_sm, raising=False)
-    mesh = meshlib.make_debug_pod_mesh()
-    P = jax.sharding.PartitionSpec
-    steplib._shard_map(lambda x: x, mesh, (P(),), P())
-    assert seen == {"check_rep": False}
-
-
-def test_shard_map_shim_experimental_home_executes():
-    """Without jax.shard_map the shim resolves the experimental home —
-    and the result is a REAL shard_map: collectives over the pod axis
-    execute."""
-    assert not hasattr(jax, "shard_map") or True  # either home is fine
-    mesh = meshlib.make_debug_pod_mesh()
-    P = jax.sharding.PartitionSpec
-    fn = steplib._shard_map(
-        lambda x: jax.lax.psum(x, "pod"), mesh, (P(),), P())
+    fn = jax.shard_map(lambda x: jax.lax.psum(x, "pod"), mesh=mesh,
+                       in_specs=(P(),), out_specs=P(), check_vma=False)
     x = jnp.arange(4.0)
     np.testing.assert_allclose(
         jax.jit(fn)(x), x * mesh.shape["pod"])
+
+
+# ---------------------------------------------------------------------------
+# the entry points: depth cut, returned metrics, compile cache
+# ---------------------------------------------------------------------------
+
+
+def test_get_config_layers_cuts_depth_only():
+    from repro.configs import get_config
+    full = get_config("internlm2-1.8b")
+    cut = get_config("internlm2-1.8b", layers=4)
+    assert cut.n_layers == 4 and full.n_layers == 24
+    assert dataclasses.replace(cut, n_layers=24) == full
+    assert get_config("internlm2-1.8b", layers=0) is full
+
+
+def test_train_main_returns_metrics():
+    """`train.main` returns its last metrics and the compiled step it
+    ran (whose state it donated), so callers need not parse stdout."""
+    from repro.launch import train
+    out = train.main(["--smoke", "--algo", "fedpm_reg", "--steps", "2",
+                      "--round-every", "1", "--cohorts", "2",
+                      "--seq", "16"])
+    assert np.isfinite(out["loss"])
+    assert out["rounds"] == 2
+    assert 0.0 < out["uplink_bpp"] <= 1.0
+    assert out["bits_measured"] > 0
+    assert out["compile_s"] > 0 and out["step_s"] >= 0
+    assert out["compiled_step"].memory_analysis() is not None
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache sits at the checkout's fixed .jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            jax.config.update("jax_compilation_cache_dir", None)
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir is None
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = compile_cache.enable_compile_cache()
+            root = os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

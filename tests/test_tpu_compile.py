@@ -1,0 +1,158 @@
+"""Compile the main path for a TPU v5e that is described, not attached.
+
+The TPU compiler is installed with jax, so Mosaic refuses here what the
+chip would refuse (casts it lacks, block shapes off the tiling, layouts
+XLA and Mosaic disagree on, programs that do not fit the device) at no
+chip time.  Nothing runs: these tests only lower and compile, at the
+widths of internlm2-1.8b (d_model 2048, d_ff 8192, vocab 92544), and
+check that each program holds its Pallas kernel (`tpu_custom_call`).
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import bitpack as _bp
+from repro.kernels import masked_matmul as _mm
+from repro.kernels import ops
+
+M = 1024                       # batch 2 x seq 512
+D, F, V = 2048, 8192, 92544    # internlm2-1.8b widths
+E, DE, FE = 64, 2048, 1408     # deepseek-v2-lite routed experts
+HBM_BYTES = 16 * 2**30         # one v5e
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to rehearse
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without the chip: keep the cache out
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _dense_blocks(K, N):
+    """The padding and blocks `ops.masked_dense` launches with."""
+    Kp, Np = ops._round_up(K, 128), ops._round_up(N, 128)
+    return Kp, Np, dict(bm=128, bn=ops._block_for(Np),
+                        bk=ops._block_for(Kp), n_logical=N,
+                        interpret=False)
+
+
+_LEAVES = {"up": (D, F), "down": (F, D), "head": (D, V)}
+bf, f32, u32 = jnp.bfloat16, jnp.float32, jnp.uint32
+
+
+@pytest.mark.parametrize("leaf", sorted(_LEAVES))
+@pytest.mark.parametrize("kernel", ["fwd", "dx", "ds"])
+def test_masked_matmul_compiles(one_chip, kernel, leaf):
+    K, N = _LEAVES[leaf]
+    Kp, Np, kw = _dense_blocks(K, N)
+    w = [((Kp, Np), bf), ((Kp, Np), f32)]
+    if kernel == "fwd":
+        c = _compile(lambda x, w, s, sd, o: _mm.masked_matmul(
+            x, w, s, sd, o, **kw), one_chip, ((M, Kp), bf), *w,
+            ((), u32), ((), u32))
+    elif kernel == "dx":
+        c = _compile(lambda g, w, s, sd, o: _mm.masked_matmul_dx(
+            g, w, s, sd, o, **kw), one_chip, ((M, Np), bf), *w,
+            ((), u32), ((), u32))
+    else:
+        del kw["n_logical"]
+        c = _compile(lambda x, g, w, s: _mm.masked_matmul_ds(
+            x, g, w, s, **kw), one_chip, ((M, Kp), bf), ((M, Np), bf),
+            *w)
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("mode", ["sample", "threshold"])
+def test_sample_and_pack_compiles(one_chip, mode):
+    c = _compile(lambda s, sd: _mm.sample_and_pack(
+        s, sd, mode=mode, interpret=False), one_chip,
+        ((2, D * F), f32), ((2,), u32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["pack", "unpack"])
+def test_bitpack_compiles(one_chip, kernel):
+    if kernel == "pack":
+        c = _compile(lambda m: _bp.pack_bits(m, interpret=False),
+                     one_chip, ((D * F,), jnp.uint8))
+    else:
+        c = _compile(lambda w: _bp.unpack_bits(w, D * F, interpret=False),
+                     one_chip, ((D * F // 32,), u32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dx", "ds"])
+def test_grouped_compiles(one_chip, kernel):
+    """One deepseek-v2-lite expert projection: E x (2048 -> 1408)."""
+    Kp, Np, kw = _dense_blocks(DE, FE)
+    Me = 128                                   # tokens per expert
+    w = [((E, Kp, Np), bf), ((E, Kp, Np), f32)]
+    if kernel == "fwd":
+        c = _compile(lambda x, w, s, sd, o: _mm.masked_matmul_grouped(
+            x, w, s, sd, o, **kw), one_chip, ((E, Me, Kp), bf), *w,
+            ((E,), u32), ((E,), u32))
+    elif kernel == "dx":
+        c = _compile(lambda g, w, s, sd, o: _mm.masked_matmul_grouped_dx(
+            g, w, s, sd, o, **kw), one_chip, ((E, Me, Np), bf), *w,
+            ((E,), u32), ((E,), u32))
+    else:
+        del kw["n_logical"]
+        c = _compile(lambda x, g, w, s: _mm.masked_matmul_grouped_ds(
+            x, g, w, s, **kw), one_chip, ((E, Me, Kp), bf),
+            ((E, Me, Np), bf), *w)
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("step", ["train", "round"])
+def test_step_compiles_and_fits(one_chip, monkeypatch, step):
+    """The whole jitted step of `chip_smoke.py`'s cut (4 layers, 2
+    cohorts, batch 2 x seq 512, state donated) compiles for one v5e,
+    holds its kernels, and fits the chip's 16 GiB."""
+    from repro.configs import get_config
+    from repro.core import masking
+    from repro.launch import steps as steplib
+    from repro.models import build_model
+    # the kernels are compiled for the chip, not interpreted as this
+    # CPU backend would choose
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    api = build_model(get_config("internlm2-1.8b", layers=4))
+    scfg = steplib.StepConfig(lr=0.3, downlink_bits=8)
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda k: steplib.init_fed_state(
+            k, api, masking.MaskSpec(), C=2), jax.random.PRNGKey(0)))
+    if step == "train":
+        fn = steplib.make_train_step(api, scfg)
+        args = (state, {"tokens": jax.ShapeDtypeStruct(
+            (2, 2, 512), jnp.int32, sharding=one_chip)})
+    else:
+        fn = steplib.make_round_step(api, scfg, codec="arithmetic")
+        args = (state,)
+    c = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    assert "tpu_custom_call" in c.as_text()
+    ma = c.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert live < HBM_BYTES, live
