@@ -55,6 +55,7 @@ def pack_bits(mask_flat: jax.Array, *, bw: int = 1024,
         m2 = jnp.pad(m2, ((0, Wp - W), (0, 0)))
     return pl.pallas_call(
         _pack_kernel,
+        name="pack_bits",
         grid=(Wp // bw_,),
         in_specs=[pl.BlockSpec((bw_, 32), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bw_,), lambda i: (i,)),
@@ -73,6 +74,7 @@ def unpack_bits(words: jax.Array, n: int, *, bw: int = 1024,
         words = jnp.pad(words, (0, Wp - W))
     bits = pl.pallas_call(
         _unpack_kernel,
+        name="unpack_bits",
         grid=(Wp // bw_,),
         in_specs=[pl.BlockSpec((bw_,), lambda i: (i,))],
         out_specs=pl.BlockSpec((bw_, 32), lambda i: (i, 0)),

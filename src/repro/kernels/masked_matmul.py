@@ -182,6 +182,7 @@ def masked_matmul(x: jax.Array, w: jax.Array, s: jax.Array,
                                nk=nk, mode=mode)
     return pl.pallas_call(
         kernel,
+        name="masked_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)),
@@ -259,6 +260,7 @@ def masked_matmul_dx(g: jax.Array, w: jax.Array, s: jax.Array,
                                n_total=n_total, nn=nn, mode=mode)
     return pl.pallas_call(
         kernel,
+        name="masked_matmul_dx",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bn_), lambda i, k, n: (i, n)),
@@ -318,6 +320,7 @@ def masked_matmul_ds(x: jax.Array, g: jax.Array, w: jax.Array,
     kernel = functools.partial(_ds_kernel, nm=nm)
     return pl.pallas_call(
         kernel,
+        name="masked_matmul_ds",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda k, n, m: (m, k)),
@@ -401,6 +404,7 @@ def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 512,
                                mode=mode, tau=tau)
     out = pl.pallas_call(
         kernel,
+        name="sample_and_pack",
         grid=(C, Wp // bw_),
         in_specs=[
             pl.BlockSpec((None, bw_, 32), lambda c, i: (c, i, 0)),
@@ -485,6 +489,7 @@ def masked_matmul_grouped(x: jax.Array, w: jax.Array, s: jax.Array,
                                n_total=n_total, nk=nk, mode=mode)
     return pl.pallas_call(
         kernel,
+        name="masked_matmul_grouped",
         grid=(E, nm, nn, nk),
         in_specs=[
             pl.BlockSpec((1, bm_, bk_), lambda e, i, j, k: (e, i, k)),
@@ -550,6 +555,7 @@ def masked_matmul_grouped_dx(g: jax.Array, w: jax.Array, s: jax.Array,
                                n_total=n_total, nn=nn, mode=mode)
     return pl.pallas_call(
         kernel,
+        name="masked_matmul_grouped_dx",
         grid=(E, nm, nk, nn),
         in_specs=[
             pl.BlockSpec((1, bm_, bn_), lambda e, i, k, n: (e, i, n)),
@@ -604,6 +610,7 @@ def masked_matmul_grouped_ds(x: jax.Array, g: jax.Array, w: jax.Array,
     kernel = functools.partial(_g_ds_kernel, nm=nm)
     return pl.pallas_call(
         kernel,
+        name="masked_matmul_grouped_ds",
         grid=(E, nk, nn, nm),
         in_specs=[
             pl.BlockSpec((1, bm_, bk_), lambda e, k, n, m: (e, m, k)),
@@ -682,6 +689,7 @@ def masked_conv1d(x_pad: jax.Array, w: jax.Array, s: jax.Array,
                                n_total=n_total, mode=mode, flip=flip)
     return pl.pallas_call(
         kernel,
+        name="masked_conv1d",
         grid=(B, C // bc_),
         in_specs=[
             pl.BlockSpec((1, Sp, bc_), lambda b, j: (b, 0, j)),
@@ -741,6 +749,7 @@ def masked_conv1d_ds(x_pad: jax.Array, g: jax.Array, w: jax.Array,
                                epilogue=epilogue)
     return pl.pallas_call(
         kernel,
+        name="masked_conv1d_ds",
         grid=(C // bc_, B),
         in_specs=[
             pl.BlockSpec((1, Sp, bc_), lambda j, b: (b, 0, j)),

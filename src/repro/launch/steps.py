@@ -175,12 +175,54 @@ def make_train_step(api, cfg: StepConfig):
                  else masking.masked_forward_tree)
         params = build(mp, seed_fn, mode=cfg.mask_mode, tau=cfg.tau)
         out = api.forward(params, batch, chunk_kv=cfg.chunk_kv)
-        loss = api.loss(out, batch)
-        reg = regularizer.entropy_proxy(scores)
+        with jax.named_scope("embed_head"):
+            loss = api.loss(out, batch)
+        with jax.named_scope("regularizer"):
+            reg = regularizer.entropy_proxy(scores)
         return loss + cfg.lam * reg, (loss, reg)
 
     def train_step(state, batch):
         C = jax.tree_util.tree_leaves(state["scores"])[0].shape[0]
+
+        @jax.named_scope("optimizer")
+        def update(scores, floats, opt_m, opt_v, gs, gf):
+            """The score update (momentum, or Adam with `opt_v`) and the
+            float leaves' SGD step."""
+            if opt_v is not None:  # adam on scores
+                b1, b2 = 0.9, 0.999
+                new_m = jax.tree_util.tree_map(
+                    lambda m, g: None if m is None else
+                    (b1 * m + (1 - b1) * g).astype(m.dtype),
+                    opt_m, gs, is_leaf=lambda x: x is None)
+                new_v = jax.tree_util.tree_map(
+                    lambda v, g: None if v is None else
+                    (b2 * v + (1 - b2) * jnp.square(
+                        g.astype(jnp.float32))).astype(v.dtype),
+                    opt_v, gs, is_leaf=lambda x: x is None)
+                t = (state["step"] + 1).astype(jnp.float32)
+                bc1 = 1 - b1 ** t
+                bc2 = 1 - b2 ** t
+                scores = jax.tree_util.tree_map(
+                    lambda s, m, v: None if s is None else
+                    (s - cfg.lr * (m / bc1) / (jnp.sqrt(v / bc2)
+                                               + cfg.adam_eps)
+                     ).astype(s.dtype),
+                    scores, new_m, new_v, is_leaf=lambda x: x is None)
+            else:
+                new_v = None
+                new_m = jax.tree_util.tree_map(
+                    lambda m, g: None if m is None else
+                    (cfg.momentum * m + g).astype(m.dtype),
+                    opt_m, gs, is_leaf=lambda x: x is None)
+                scores = jax.tree_util.tree_map(
+                    lambda s, m: None if s is None else
+                    (s - cfg.lr * m).astype(s.dtype),
+                    scores, new_m, is_leaf=lambda x: x is None)
+            floats = jax.tree_util.tree_map(
+                lambda f, g: None if f is None else
+                (f - cfg.float_lr * g).astype(f.dtype),
+                floats, gf, is_leaf=lambda x: x is None)
+            return scores, floats, new_m, new_v
 
         def one(scores, floats, opt_m, opt_v, batch_c, idx):
             if cfg.microbatch > 1:
@@ -227,40 +269,8 @@ def make_train_step(api, cfg: StepConfig):
                     cohort_loss, argnums=(0, 1), has_aux=True)(
                         scores, floats, state["weights"], batch_c,
                         state["step"], idx)
-            if opt_v is not None:  # adam on scores
-                b1, b2 = 0.9, 0.999
-                new_m = jax.tree_util.tree_map(
-                    lambda m, g: None if m is None else
-                    (b1 * m + (1 - b1) * g).astype(m.dtype),
-                    opt_m, gs, is_leaf=lambda x: x is None)
-                new_v = jax.tree_util.tree_map(
-                    lambda v, g: None if v is None else
-                    (b2 * v + (1 - b2) * jnp.square(
-                        g.astype(jnp.float32))).astype(v.dtype),
-                    opt_v, gs, is_leaf=lambda x: x is None)
-                t = (state["step"] + 1).astype(jnp.float32)
-                bc1 = 1 - b1 ** t
-                bc2 = 1 - b2 ** t
-                scores = jax.tree_util.tree_map(
-                    lambda s, m, v: None if s is None else
-                    (s - cfg.lr * (m / bc1) / (jnp.sqrt(v / bc2)
-                                               + cfg.adam_eps)
-                     ).astype(s.dtype),
-                    scores, new_m, new_v, is_leaf=lambda x: x is None)
-            else:
-                new_v = None
-                new_m = jax.tree_util.tree_map(
-                    lambda m, g: None if m is None else
-                    (cfg.momentum * m + g).astype(m.dtype),
-                    opt_m, gs, is_leaf=lambda x: x is None)
-                scores = jax.tree_util.tree_map(
-                    lambda s, m: None if s is None else
-                    (s - cfg.lr * m).astype(s.dtype),
-                    scores, new_m, is_leaf=lambda x: x is None)
-            floats = jax.tree_util.tree_map(
-                lambda f, g: None if f is None else
-                (f - cfg.float_lr * g).astype(f.dtype),
-                floats, gf, is_leaf=lambda x: x is None)
+            scores, floats, new_m, new_v = update(
+                scores, floats, opt_m, opt_v, gs, gf)
             return scores, floats, new_m, new_v, loss
 
         has_v = "opt_v" in state
@@ -383,41 +393,44 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
                 continue
             Cl = Cl_any = sl.shape[0]
             body = sl.shape[1:]
-            flat = sl.reshape(Cl, -1)
-            n = flat.shape[1]
-            seeds = _mask_stream_seeds(step, dev, i, Cl,
-                                       run_seed=cfg.seed)
+            with jax.named_scope("uplink"):
+                flat = sl.reshape(Cl, -1)
+                n = flat.shape[1]
+                seeds = _mask_stream_seeds(step, dev, i, Cl,
+                                           run_seed=cfg.seed)
+                if cfg.packed_masks:
+                    words = aggregation.sample_and_pack_rows(
+                        flat, seeds, use_kernel=True,
+                        mode=cfg.mask_mode, tau=cfg.tau)   # (Cl, W) u32
+                    ones_parts.append(jnp.sum(
+                        jax.lax.population_count(words),
+                        axis=1).astype(jnp.float32))
+                    if words_exact:
+                        word_parts.append(words)
+                    else:  # codec needs gap structure, not just counts
+                        bit_parts.append(jax.vmap(
+                            lambda wd: aggregation.unpack_bits(wd, n)
+                        )(words))
+                else:
+                    masks2 = (kref.threshold_rows(flat, cfg.tau)
+                              if cfg.mask_mode == "threshold"
+                              else kref.sample_rows(flat, seeds))
+                    ones_parts.append(jnp.sum(
+                        masks2.astype(jnp.float32), axis=1))
+                    bit_parts.append(masks2)
             if cfg.packed_masks:
-                words = aggregation.sample_and_pack_rows(
-                    flat, seeds, use_kernel=True,
-                    mode=cfg.mask_mode, tau=cfg.tau)       # (Cl, W) u32
-                ones_parts.append(jnp.sum(
-                    jax.lax.population_count(words),
-                    axis=1).astype(jnp.float32))
-                if words_exact:
-                    word_parts.append(words)
-                else:  # codec needs gap structure, not just counts
-                    bit_parts.append(jax.vmap(
-                        lambda wd: aggregation.unpack_bits(wd, n)
-                    )(words))
+                words_all = words
                 if pod_axis:
                     words_all = jax.lax.all_gather(words, pod_axis)
                     words_all = words_all.reshape(-1, words.shape[-1])
-                else:
-                    words_all = words
-                # wn_g rows follow the gather's pod-major cohort order,
-                # so the survivor-renormalized weighted mean drops in
-                # where the uniform mean was
-                theta = plds.mean_from_words(words_all, n,
-                                             weights=wn_g)
-            else:
-                masks2 = (kref.threshold_rows(flat, cfg.tau)
-                          if cfg.mask_mode == "threshold"
-                          else kref.sample_rows(flat, seeds))
-                ones_parts.append(jnp.sum(
-                    masks2.astype(jnp.float32), axis=1))
-                bit_parts.append(masks2)
-                if part is None:
+            with jax.named_scope("fold"):
+                if cfg.packed_masks:
+                    # wn_g rows follow the gather's pod-major cohort
+                    # order, so the survivor-renormalized weighted mean
+                    # drops in where the uniform mean was
+                    theta = plds.mean_from_words(words_all, n,
+                                                 weights=wn_g)
+                elif part is None:
                     b = jnp.mean(masks2.astype(jnp.bfloat16), axis=0)
                     if pod_axis:
                         b = jax.lax.pmean(b, pod_axis)
@@ -428,8 +441,8 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
                     if pod_axis:
                         b = jax.lax.psum(b, pod_axis)
                     theta = b
+                theta_flat.append(theta.reshape(body))
             n_pool += n
-            theta_flat.append(theta.reshape(body))
         theta = jax.tree_util.tree_unflatten(tdef, theta_flat)
         if cfg.downlink_bits:
             # the orphaned k-bit downlink, live: theta crosses the wire
@@ -438,87 +451,91 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
             # with dev=0 — every shard uses the same key, so cohorts
             # keep receiving identical broadcasts, and distinct
             # (run_seed, step) pairs quantize under distinct keys
-            qkey = jax.random.PRNGKey(masking.mask_stream_seed(
-                step, 0, _DOWNLINK_STREAM_LEAF, 0, run_seed=cfg.seed))
-            theta = aggregation.dequantize_theta(
-                aggregation.quantize_theta(theta, qkey,
-                                           bits=cfg.downlink_bits),
-                bits=cfg.downlink_bits)
-        new_scores = jax.tree_util.tree_map(
-            lambda t, s: None if t is None else jnp.broadcast_to(
-                masking.logit(t)[None], s.shape).astype(cfg.score_dtype),
-            theta, scores, is_leaf=lambda x: x is None)
-        if part is not None:
-            # survivor-weighted float fold: dead cohorts' local floats
-            # contribute zero weight, the psum renormalizes globally
-            def _wavg(f):
-                if f is None:
-                    return None
-                s = jnp.tensordot(wn_l, f.astype(jnp.float32),
-                                  axes=(0, 0))
-                if has_pod:
-                    s = jax.lax.psum(s, "pod")
-                return jnp.broadcast_to(s[None],
-                                        f.shape).astype(f.dtype)
-            new_floats = jax.tree_util.tree_map(
-                _wavg, floats, is_leaf=lambda x: x is None)
-        elif has_pod:
-            new_floats = jax.tree_util.tree_map(
-                lambda f: None if f is None else
-                (jax.lax.pmean(f.astype(jnp.float32), "pod")
-                 ).astype(f.dtype),
-                floats, is_leaf=lambda x: x is None)
-        else:
-            new_floats = jax.tree_util.tree_map(
-                lambda f: None if f is None else jnp.broadcast_to(
-                    jnp.mean(f.astype(jnp.float32), 0)[None],
-                    f.shape).astype(f.dtype),
-                floats, is_leaf=lambda x: x is None)
-        new_opt = jax.tree_util.tree_map(
-            lambda m: None if m is None else jnp.zeros_like(m),
-            opt_m, is_leaf=lambda x: x is None)
+            with jax.named_scope("downlink"):
+                qkey = jax.random.PRNGKey(masking.mask_stream_seed(
+                    step, 0, _DOWNLINK_STREAM_LEAF, 0, run_seed=cfg.seed))
+                theta = aggregation.dequantize_theta(
+                    aggregation.quantize_theta(theta, qkey,
+                                               bits=cfg.downlink_bits),
+                    bits=cfg.downlink_bits)
+        with jax.named_scope("fold"):
+            new_scores = jax.tree_util.tree_map(
+                lambda t, s: None if t is None else jnp.broadcast_to(
+                    masking.logit(t)[None], s.shape).astype(cfg.score_dtype),
+                theta, scores, is_leaf=lambda x: x is None)
+            if part is not None:
+                # survivor-weighted float fold: dead cohorts' local floats
+                # contribute zero weight, the psum renormalizes globally
+                def _wavg(f):
+                    if f is None:
+                        return None
+                    s = jnp.tensordot(wn_l, f.astype(jnp.float32),
+                                      axes=(0, 0))
+                    if has_pod:
+                        s = jax.lax.psum(s, "pod")
+                    return jnp.broadcast_to(s[None],
+                                            f.shape).astype(f.dtype)
+                new_floats = jax.tree_util.tree_map(
+                    _wavg, floats, is_leaf=lambda x: x is None)
+            elif has_pod:
+                new_floats = jax.tree_util.tree_map(
+                    lambda f: None if f is None else
+                    (jax.lax.pmean(f.astype(jnp.float32), "pod")
+                     ).astype(f.dtype),
+                    floats, is_leaf=lambda x: x is None)
+            else:
+                new_floats = jax.tree_util.tree_map(
+                    lambda f: None if f is None else jnp.broadcast_to(
+                        jnp.mean(f.astype(jnp.float32), 0)[None],
+                        f.shape).astype(f.dtype),
+                    floats, is_leaf=lambda x: x is None)
+            new_opt = jax.tree_util.tree_map(
+                lambda m: None if m is None else jnp.zeros_like(m),
+                opt_m, is_leaf=lambda x: x is None)
         # local bpp estimate (same value on every device up to shard
         # composition; cheap diagnostic) — the paper's eq. 13 meter,
         # computed from the popcounts so the packed path never
         # re-materializes the uint8 mask the fused kernel avoided
-        if n_pool:
-            ones_c = sum(ones_parts)                       # (Cl,)
-            if part is None:
-                p1 = jnp.sum(ones_c) / jnp.float32(n_pool * Cl_any)
-            else:  # survivors only: dead cohorts sent nothing
-                p1 = (jnp.sum(ones_c * alive_l)
-                      / (jnp.float32(n_pool)
-                         * jnp.maximum(jnp.sum(alive_l), 1.0)))
-            bpp = regularizer.binary_entropy(p1)
-        else:
-            bpp = jnp.float32(0.0)
-        # measured wire bits: pool every leaf's stream per cohort and
-        # ask the codec — the same measure_* primitives the host-sim
-        # engine meters payloads with.  Popcount-exact codecs (bitpack,
-        # arithmetic) meter the packed words directly; others get the
-        # unpacked pooled bits.  Each shard codes its own slice-stream;
-        # the psum over EVERY mesh axis makes the returned value the
-        # exact total of all shards' streams (and genuinely replicated,
-        # as the out_spec declares).
-        if word_parts:
-            pooled = jnp.concatenate(word_parts, axis=1)
-            per_cohort = jax.vmap(
-                lambda wr: codec.measure_pooled_words(wr, n_pool)
-            )(pooled)
-        elif bit_parts:
-            pooled = jnp.concatenate(bit_parts, axis=1).astype(jnp.uint8)
-            per_cohort = jax.vmap(codec.measure_pooled_bits)(pooled)
-        else:
-            per_cohort = jnp.zeros((1,), jnp.int32)
-        per_cohort = per_cohort.astype(jnp.float32)
-        if part is not None and per_cohort.shape[0] == Cl_loc:
-            per_cohort = per_cohort * alive_l   # dead uplinks: 0 bits
-        bits_total = jnp.sum(per_cohort)
-        if mesh is not None:
-            bits_total = jax.lax.psum(bits_total,
-                                      tuple(mesh.axis_names))
+        with jax.named_scope("codec_meter"):
+            if n_pool:
+                ones_c = sum(ones_parts)                       # (Cl,)
+                if part is None:
+                    p1 = jnp.sum(ones_c) / jnp.float32(n_pool * Cl_any)
+                else:  # survivors only: dead cohorts sent nothing
+                    p1 = (jnp.sum(ones_c * alive_l)
+                          / (jnp.float32(n_pool)
+                             * jnp.maximum(jnp.sum(alive_l), 1.0)))
+                bpp = regularizer.binary_entropy(p1)
+            else:
+                bpp = jnp.float32(0.0)
+            # measured wire bits: pool every leaf's stream per cohort and
+            # ask the codec — the same measure_* primitives the host-sim
+            # engine meters payloads with.  Popcount-exact codecs (bitpack,
+            # arithmetic) meter the packed words directly; others get the
+            # unpacked pooled bits.  Each shard codes its own slice-stream;
+            # the psum over EVERY mesh axis makes the returned value the
+            # exact total of all shards' streams (and genuinely replicated,
+            # as the out_spec declares).
+            if word_parts:
+                pooled = jnp.concatenate(word_parts, axis=1)
+                per_cohort = jax.vmap(
+                    lambda wr: codec.measure_pooled_words(wr, n_pool)
+                )(pooled)
+            elif bit_parts:
+                pooled = jnp.concatenate(bit_parts, axis=1).astype(jnp.uint8)
+                per_cohort = jax.vmap(codec.measure_pooled_bits)(pooled)
+            else:
+                per_cohort = jnp.zeros((1,), jnp.int32)
+            per_cohort = per_cohort.astype(jnp.float32)
+            if part is not None and per_cohort.shape[0] == Cl_loc:
+                per_cohort = per_cohort * alive_l   # dead uplinks: 0 bits
+            bits_total = jnp.sum(per_cohort)
+            if mesh is not None:
+                bits_total = jax.lax.psum(bits_total,
+                                          tuple(mesh.axis_names))
         return new_scores, new_floats, new_opt, bpp, bits_total
 
+    @jax.named_scope("fold")
     def _zero_v(st, out):
         if "opt_v" in st:
             out["opt_v"] = jax.tree_util.tree_map(

@@ -256,6 +256,7 @@ def _attn_scores_mask(q_pos, k_pos, window: int | None, causal=True):
     return jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
 
 
+@jax.named_scope("attention_core")
 def attention_core(q, k, v, q_pos, k_pos, window=None, causal=True,
                    chunk_kv: int | None = None, soft_cap: float | None = None):
     """q: (B, Sq, H, Hd); k: (B, Sk, Kv, Hd); v: (B, Sk, Kv, Dv).

@@ -97,27 +97,30 @@ def layer_windows(cfg: ArchConfig, n: int):
 
 def _block(cfg: ArchConfig, moe: bool, x, lp, positions, window, theta,
            chunk_kv, mrope_positions):
-    h = L.rms_norm(lp["attn_norm"], x)
-    if cfg.kv_lora_rank:
-        attn_out, kv = L.mla_apply(
-            lp["attn"], h, positions, cfg.n_heads, cfg.kv_lora_rank,
-            cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
-            rope_theta=cfg.rope_theta, chunk_kv=chunk_kv)
-    else:
-        attn_out, kv = L.gqa_apply(
-            lp["attn"], h, positions, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-            window=window, causal=True, rope_theta=theta,
-            chunk_kv=chunk_kv, mrope_positions=mrope_positions,
-            mrope_sections=cfg.mrope_sections if mrope_positions is not None
-            else None)
-    x = x + attn_out
-    h = L.rms_norm(lp["ffn_norm"], x)
+    with jax.named_scope("attention"):
+        h = L.rms_norm(lp["attn_norm"], x)
+        if cfg.kv_lora_rank:
+            attn_out, kv = L.mla_apply(
+                lp["attn"], h, positions, cfg.n_heads, cfg.kv_lora_rank,
+                cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
+                rope_theta=cfg.rope_theta, chunk_kv=chunk_kv)
+        else:
+            attn_out, kv = L.gqa_apply(
+                lp["attn"], h, positions, cfg.n_heads, cfg.n_kv_heads,
+                cfg.hd, window=window, causal=True, rope_theta=theta,
+                chunk_kv=chunk_kv, mrope_positions=mrope_positions,
+                mrope_sections=cfg.mrope_sections
+                if mrope_positions is not None else None)
+        x = x + attn_out
     if moe:
+        h = L.rms_norm(lp["ffn_norm"], x)
         ffn_out, aux = L.moe_apply(lp["moe"], h, cfg.n_experts, cfg.top_k,
                                    cfg.capacity_factor,
                                    block_dispatch=cfg.moe_block_dispatch)
     else:
-        ffn_out, aux = L.mlp_apply(lp["mlp"], h, cfg.act), 0.0
+        with jax.named_scope("mlp"):
+            h = L.rms_norm(lp["ffn_norm"], x)
+            ffn_out, aux = L.mlp_apply(lp["mlp"], h, cfg.act), 0.0
     return x + ffn_out, kv, aux
 
 
@@ -130,8 +133,9 @@ def forward(params: Pytree, cfg: ArchConfig, tokens: jax.Array,
 
     Returns (logits, aux_loss) or (logits, aux_loss, cache).
     """
-    x = L.embed_lookup(params["embed"]["table"], tokens)
-    x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    with jax.named_scope("embed_head"):
+        x = L.embed_lookup(params["embed"]["table"], tokens)
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     mrope_positions = None
     if vis_embeds is not None:
         x = jnp.concatenate([vis_embeds.astype(x.dtype), x], axis=1)
@@ -194,9 +198,10 @@ def forward(params: Pytree, cfg: ArchConfig, tokens: jax.Array,
         if collect_cache:
             caches["moe"] = kvs
 
-    x = L.rms_norm(params["final_norm"], x)
-    head = params.get("lm_head", params["embed"])["table"]
-    logits = L.unembed(head, x)
+    with jax.named_scope("embed_head"):
+        x = L.rms_norm(params["final_norm"], x)
+        head = params.get("lm_head", params["embed"])["table"]
+        logits = L.unembed(head, x)
     if cfg.logit_sharding:
         logits = jax.lax.with_sharding_constraint(
             logits, jax.sharding.PartitionSpec(*cfg.logit_sharding))

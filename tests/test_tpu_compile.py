@@ -11,6 +11,10 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import pathlib
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +23,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import bitpack as _bp
 from repro.kernels import masked_matmul as _mm
 from repro.kernels import ops
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+
+from benchmarks.chip import scope_reduce as SR  # noqa: E402
+from benchmarks.chip import trace_reduce as TR  # noqa: E402
 
 M = 1024                       # batch 2 x seq 512
 D, F, V = 2048, 8192, 92544    # internlm2-1.8b widths
@@ -128,7 +137,8 @@ def test_grouped_compiles(one_chip, kernel):
 def test_step_compiles_and_fits(one_chip, monkeypatch, step):
     """The whole jitted step of `chip_smoke.py`'s cut (4 layers, 2
     cohorts, batch 2 x seq 512, state donated) compiles for one v5e,
-    holds its kernels, and fits the chip's 16 GiB."""
+    holds its kernels under their fixed names, and fits the chip's 16
+    GiB."""
     from repro.configs import get_config
     from repro.core import masking
     from repro.launch import steps as steplib
@@ -151,7 +161,20 @@ def test_step_compiles_and_fits(one_chip, monkeypatch, step):
         fn = steplib.make_round_step(api, scfg, codec="arithmetic")
         args = (state,)
     c = jax.jit(fn, donate_argnums=0).lower(*args).compile()
-    assert "tpu_custom_call" in c.as_text()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    # the kernels' calls are named by `pallas_call(name=...)`, whatever
+    # scope calls them, and each kernel reader's pattern finds its own
+    calls = {TR.base_name(n) for n in re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+    kernels = (("masked_matmul", "masked_matmul_dx", "masked_matmul_ds")
+               if step == "train" else ("sample_and_pack",))
+    assert calls == set(kernels), calls
+    found = TR.Summary(window_s=0, busy_s=0, op_s={}, op_count={},
+                       gaps=[], devices=1, custom=calls)
+    for k in kernels:
+        assert found.kernels((k,)) == [k], (k, calls)
+    assert not calls & set(SR.TRAIN_SCOPES + SR.ROUND_SCOPES), calls
     ma = c.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
