@@ -14,6 +14,8 @@ One chip, in one process:
       512, a few masked train steps and two rounds.  The loss must be
       finite, `uplink_bpp` in (0, 1], the measured bits > 0, and the
       compiled train step must hold Pallas kernels (`tpu_custom_call`).
+      Prints each masked projection's block plan (`ops.dense_plan`):
+      `passes` is how often the forward and dx stream `w` and `s`.
   (b) `sample_and_pack` words of one 2048 x 8192 leaf at C=2 must EQUAL
       the pure-jnp oracle `kernels.ref.sample_and_pack`.
   (c) `ops.masked_dense` forward, dx and ds at that leaf against the jnp
@@ -114,6 +116,29 @@ def phase_train(seed: int) -> dict:
             f"uplink_bpp {out['uplink_bpp']} not in (0, 1]")
     require(out["bits_measured"] > 0, "no measured uplink bits")
     return out
+
+
+def projection_passes(jax) -> None:
+    """How many times the forward and dx kernels stream each tile of `w`
+    and `s` per call (`ops.dense_plan(...).passes`), for every masked
+    projection of the train step at one cohort's BATCH x SEQ tokens."""
+    from repro.configs import get_config
+    from repro.core import masking
+    from repro.kernels import ops
+    from repro.launch import steps as steplib
+    from repro.models import build_model
+    api = build_model(get_config(ARCH, layers=LAYERS))
+    state = jax.eval_shape(lambda k: steplib.init_fed_state(
+        k, api, masking.MaskSpec(), C=COHORTS), jax.random.PRNGKey(0))
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            state["scores"], is_leaf=lambda x: x is None)[0]:
+        if leaf is None:
+            continue
+        K, N = leaf.shape[-2:]
+        plan = ops.dense_plan(BATCH * SEQ, K, N)
+        print(f"(a) {jax.tree_util.keystr(path)} ({K} x {N}) at "
+              f"M={BATCH * SEQ}: bm {plan.bm}, bn {plan.bn}, bk "
+              f"{plan.bk}, passes {plan.passes}")
 
 
 def phase_pack(jax, seed: int) -> None:
@@ -222,6 +247,7 @@ def run_one_chip(jax, seed: int) -> None:
     t_train = time.perf_counter() - t0
     peak_train = _mem(jax.devices()[0])
     del out["compiled_step"]
+    projection_passes(jax)
     phase_pack(jax, seed)
     phase_dense(jax, seed)
     phase_serve(seed)

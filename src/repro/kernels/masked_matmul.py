@@ -2,9 +2,12 @@
 
 Forward:   y  = x @ (m ⊙ w),   m = 1[u < sigmoid(s)],  u = hash(seed, idx)
 
-in ONE pass: tiles of `w` and `s` stream HBM->VMEM once per (k, n) tile,
-the Bernoulli mask is formed in VMEM/VREGs from a counter-based hash
-(no RNG state, no mask tensor in HBM), the gated tile feeds the MXU.
+in ONE pass: each (k, n) tile of `w` and `s` streams HBM->VMEM, and
+its Bernoulli mask is formed in VMEM/VREGs from a counter-based hash
+(no RNG state, no mask tensor in HBM), once per token block of `bm`
+rows; the gated tile feeds the MXU.  `ops.dense_plan` makes that once
+per call: it takes `bm` as the whole padded token count wherever the
+working set fits its VMEM budget.
 
 Backward (STE, see ops.py): two more kernels with the same property —
 
@@ -57,10 +60,16 @@ FedMask predicate m = 1[sigmoid(s) > tau] (tau rides as a runtime
 scalar operand, so no retrace per tau); the hash/seed/off operands are
 ignored in that mode.
 
-Block shapes default to (128, 512, 512) — MXU-aligned (multiples of
-128) and VMEM-safe: bm*bk + 2*bk*bn + bm*bn tiles ≈ 128*512*4B +
-2*512*512*(2+4)B + 128*512*4B ≈ 1.9 MB « 16 MB v5e VMEM, leaving room
-for double-buffering.
+Blocks: bn and bk are 128, 256 or 512 (MXU-aligned).  The forward and
+dx grids put the token axis outermost, so a tile of `w` and `s` is
+fetched once per token block: `ops.dense_plan` picks bm as the largest
+multiple of 128 dividing the padded token count whose working set fits
+`VMEM_BUDGET` (32 MiB; every operand counted at 4 B: the double-
+buffered activation, output, `w` and `s` blocks, the f32 accumulator
+and the body's f32 temporaries, 12*bm*(bk+bn) + 24*bk*bn bytes, 18 MiB
+at bm = 1024, bk = bn = 512), and compiles the kernels with that
+budget as their scoped VMEM limit.  The ds kernel's grid puts the
+token axis innermost, so it reads each tile once at bm = 128.
 """
 from __future__ import annotations
 
@@ -146,6 +155,12 @@ def _scalar_operands(seed, off, tau):
             jnp.asarray(tau, jnp.float32).reshape(1, 1))
 
 
+# VMEM that `ops.dense_plan` sizes the forward and dx blocks against,
+# and the scoped limit both kernels are compiled with (Mosaic's default
+# is 16 MiB; a v5e core has 128 MiB).
+VMEM_BUDGET = 32 * 2**20
+_DENSE_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET)
+
 # Scalar operands live whole in SMEM as (1, n) arrays.  The leading unit
 # axis is what keeps them legal under `vmap` (the cohort axis of the
 # train step): batching prepends a squeezed block dim, and Mosaic only
@@ -192,6 +207,7 @@ def masked_matmul(x: jax.Array, w: jax.Array, s: jax.Array,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
+        compiler_params=_DENSE_PARAMS,
         interpret=interpret,
     )(x, w, s, *_scalar_operands(seed, off, tau))
 
@@ -270,6 +286,7 @@ def masked_matmul_dx(g: jax.Array, w: jax.Array, s: jax.Array,
         out_specs=pl.BlockSpec((bm_, bk_), lambda i, k, n: (i, k)),
         out_shape=jax.ShapeDtypeStruct((M, K), g.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bk_), jnp.float32)],
+        compiler_params=_DENSE_PARAMS,
         interpret=interpret,
     )(g, w, s, *_scalar_operands(seed, off, tau))
 

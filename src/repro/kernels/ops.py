@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -139,6 +140,43 @@ def _pad2(a: jax.Array, r: int, c: int) -> jax.Array:
     return jnp.pad(a, ((0, pr), (0, pc)))
 
 
+class DensePlan(NamedTuple):
+    """Blocks of one forward or dx launch.  `passes` = Mp // bm is how
+    many times each (k, n) tile of `w` and `s` streams HBM->VMEM, and
+    its mask is drawn, per call."""
+    bm: int
+    bn: int
+    bk: int
+    passes: int
+
+
+def _dense_vmem_bytes(bm: int, bn: int, bk: int) -> int:
+    """VMEM working set of one forward or dx grid step, every operand
+    counted at 4 B (callers pass bf16 or f32).  The forward reads an
+    (bm, bk) activation block and writes (bm, bn); dx the other way
+    round, so both hold bm*(bk+bn) activation elements per buffer:
+    double-buffered in and out blocks (2x), the f32 accumulator and the
+    f32 cast of the in block (1x); and bk*bn tile elements: double-
+    buffered `w` and `s` (4x), the body's f32 sigmoid and m*w (2x)."""
+    act, tile = bm * (bk + bn) * 4, bk * bn * 4
+    return 3 * act + 6 * tile
+
+
+def dense_plan(M: int, K: int, N: int) -> DensePlan:
+    """Blocks for an (M, K) x (K, N) masked matmul, padded to 128.
+    bn and bk are `_block_for` the padded N and K; bm is the largest
+    multiple of 128 dividing Mp whose working set fits the kernels'
+    VMEM limit (`masked_matmul.VMEM_BUDGET`).  So the whole token count
+    rides one block (passes == 1) wherever it fits, and only very long
+    calls (im2col convs) stream `w` and `s` more than once."""
+    Mp, Kp, Np = (_round_up(M, 128), _round_up(K, 128),
+                  _round_up(N, 128))
+    bn, bk = _block_for(Np), _block_for(Kp)
+    bm = next(b for b in range(Mp, 0, -128) if Mp % b == 0
+              and _dense_vmem_bytes(b, bn, bk) <= _mm.VMEM_BUDGET)
+    return DensePlan(bm, bn, bk, Mp // bm)
+
+
 def _fused_fwd(x, w, s, seed, off, tau, mode):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
@@ -146,9 +184,10 @@ def _fused_fwd(x, w, s, seed, off, tau, mode):
     K, N = w.shape
     Mp, Kp, Np = (_round_up(M, 128), _round_up(K, 128),
                   _round_up(N, 128))
+    plan = dense_plan(M, K, N)
     y = _mm.masked_matmul(
         _pad2(x2, Mp, Kp), _pad2(w, Kp, Np), _pad2(s, Kp, Np), seed,
-        off, bm=128, bn=_block_for(Np), bk=_block_for(Kp), n_logical=N,
+        off, bm=plan.bm, bn=plan.bn, bk=plan.bk, n_logical=N,
         interpret=_use_interpret(), mode=mode, tau=tau)[:M, :N]
     return y.reshape(shape[:-1] + (N,))
 
@@ -169,15 +208,18 @@ def _fused_bwd(x, w, s, seed, off, tau, mode, g):
     M = x2.shape[0]
     Mp, Kp, Np = (_round_up(M, 128), _round_up(K, 128),
                   _round_up(N, 128))
-    bn, bk = _block_for(Np), _block_for(Kp)
+    plan = dense_plan(M, K, N)
     interp = _use_interpret()
     xp, gp = _pad2(x2, Mp, Kp), _pad2(g2, Mp, Np)
     wp, sp = _pad2(w, Kp, Np), _pad2(s, Kp, Np)
-    dx = _mm.masked_matmul_dx(gp, wp, sp, seed, off, bm=128, bn=bn,
-                              bk=bk, n_logical=N, interpret=interp,
-                              mode=mode, tau=tau)[:M, :K]
-    ds = _mm.masked_matmul_ds(xp, gp, wp, sp, bm=128, bn=bn, bk=bk,
-                              interpret=interp)[:K, :N]
+    dx = _mm.masked_matmul_dx(gp, wp, sp, seed, off, bm=plan.bm,
+                              bn=plan.bn, bk=plan.bk, n_logical=N,
+                              interpret=interp, mode=mode,
+                              tau=tau)[:M, :K]
+    # ds keeps bm=128: its grid has m innermost, so it streams each
+    # tile of w and s once per call already
+    ds = _mm.masked_matmul_ds(xp, gp, wp, sp, bm=128, bn=plan.bn,
+                              bk=plan.bk, interpret=interp)[:K, :N]
     return (dx.reshape(x.shape).astype(x.dtype), ds.astype(s.dtype))
 
 

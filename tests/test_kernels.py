@@ -15,7 +15,7 @@ from repro.kernels.masked_matmul import (masked_matmul, masked_matmul_dx,
                                          masked_matmul_grouped,
                                          masked_matmul_grouped_dx,
                                          masked_matmul_grouped_ds,
-                                         sample_and_pack)
+                                         sample_and_pack, VMEM_BUDGET)
 from repro.kernels.bitpack import pack_bits, unpack_bits
 
 
@@ -183,6 +183,91 @@ def test_fwd_bwd_ref_masks_bit_identical_across_tilings(blocks):
     m_ref = ref.sample_mask(s, 99).astype(jnp.float32)
     assert np.array_equal(np.asarray(m_fwd), np.asarray(m_ref))
     assert np.array_equal(np.asarray(m_dx).T, np.asarray(m_ref))
+
+
+@pytest.mark.parametrize("mode", ["sample", "threshold"])
+@pytest.mark.parametrize("M", [384, 512])
+def test_planned_bm_masks_bit_identical_to_bm128(M, mode):
+    """`ops.dense_plan` takes the whole token count as one block (one
+    pass over w and s per call); the forward and dx masks drawn at that
+    bm equal those at bm=128, and the oracle's, bit for bit (w = 1 and
+    an identity input return the mask exactly)."""
+    K = N = M
+    plan = ops.dense_plan(M, K, N)
+    assert (plan.bm, plan.passes) == (M, 1)
+    s = jax.random.normal(jax.random.PRNGKey(M), (K, N), jnp.float32)
+    w = jnp.ones((K, N), jnp.float32)
+    eye = jnp.eye(M, dtype=jnp.float32)
+    kw = dict(bn=plan.bn, bk=plan.bk, interpret=True, mode=mode, tau=0.6)
+    m_ref = (ref.sample_mask(s, 99, 5) if mode == "sample"
+             else ref.threshold_mask(s, 0.6)).astype(jnp.float32)
+    for bm in (plan.bm, 128):
+        m_fwd = masked_matmul(eye, w, s, 99, 5, bm=bm, **kw)
+        m_dx = masked_matmul_dx(eye, w, s, 99, 5, bm=bm, **kw)
+        assert np.array_equal(np.asarray(m_fwd), np.asarray(m_ref)), bm
+        assert np.array_equal(np.asarray(m_dx).T, np.asarray(m_ref)), bm
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dx"])
+@pytest.mark.parametrize("M", [384, 512])
+def test_planned_bm_matches_bm128(kernel, M):
+    """Forward and dx at the planned bm against bm=128 on random bf16
+    operands.  Both sum the same k (forward) or n (dx) blocks in the
+    same order into an f32 accumulator; only the dot inside one block
+    may sum its terms in another order when it has more rows, which
+    moves an f32 sum by at most T * 2^-24 of the sum of the terms'
+    magnitudes (T terms per block), and the bf16 result then rounds to
+    within one ulp (2^-8 of the value) of the other."""
+    K, N = 256, 512
+    plan = ops.dense_plan(M, K, N)
+    assert (plan.bm, plan.passes) == (M, 1)
+    kx, kw_, ks = jax.random.split(jax.random.PRNGKey(M + 7), 3)
+    w = jax.random.normal(kw_, (K, N), jnp.float32).astype(jnp.bfloat16)
+    s = jax.random.normal(ks, (K, N), jnp.float32)
+    wm = ref.sample_mask(s, 17, 3).astype(jnp.float32) \
+        * w.astype(jnp.float32)
+    kw = dict(bn=plan.bn, bk=plan.bk, interpret=True)
+    if kernel == "fwd":
+        a = jax.random.normal(kx, (M, K), jnp.float32).astype(jnp.bfloat16)
+        run = lambda bm: masked_matmul(a, w, s, 17, 3, bm=bm, **kw)
+        mag, T = jnp.abs(a.astype(jnp.float32)) @ jnp.abs(wm), plan.bk
+    else:
+        a = jax.random.normal(kx, (M, N), jnp.float32).astype(jnp.bfloat16)
+        run = lambda bm: masked_matmul_dx(a, w, s, 17, 3, bm=bm, **kw)
+        mag, T = jnp.abs(a.astype(jnp.float32)) @ jnp.abs(wm).T, plan.bn
+    big = np.asarray(run(plan.bm), np.float32)
+    small = np.asarray(run(128), np.float32)
+    tol = 2.0**-8 * np.abs(small) + T * 2.0**-24 * np.asarray(mag)
+    assert np.all(np.abs(big - small) <= tol)
+
+
+@pytest.mark.parametrize("proj,K,N", [
+    ("w_q", 2048, 2048), ("w_k", 2048, 1024), ("w_v", 2048, 1024),
+    ("w_o", 2048, 2048), ("w_gate", 2048, 8192), ("w_up", 2048, 8192),
+    ("w_down", 8192, 2048)])
+def test_dense_plan_one_pass_at_train_step(proj, K, N):
+    """internlm2-1.8b's projections at one cohort's batch 2 x seq 512:
+    the whole token count is one block, so w and s stream once a call,
+    inside the VMEM budget the kernels are compiled with."""
+    plan = ops.dense_plan(1024, K, N)
+    assert (plan.bm, plan.passes) == (1024, 1), (proj, plan)
+    assert ops._dense_vmem_bytes(plan.bm, plan.bn, plan.bk) \
+        <= VMEM_BUDGET
+
+
+@pytest.mark.parametrize("K,N", [(2048, 2048), (288, 64), (1152, 128)])
+def test_dense_plan_caps_long_calls(K, N):
+    """An im2col-sized token count does not fit one block: the budget
+    caps bm, and w and s stream more than once."""
+    M = 65536
+    plan = ops.dense_plan(M, K, N)
+    assert plan.passes > 1 and plan.bm * plan.passes == M, plan
+    assert plan.bm % 128 == 0
+    assert ops._dense_vmem_bytes(plan.bm, plan.bn, plan.bk) \
+        <= VMEM_BUDGET
+    # the largest such block: twice it would not fit, or not divide M
+    assert (M % (2 * plan.bm) or ops._dense_vmem_bytes(
+        2 * plan.bm, plan.bn, plan.bk) > VMEM_BUDGET)
 
 
 def test_padded_launch_mask_matches_ref_bit_exact():

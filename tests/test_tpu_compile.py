@@ -58,8 +58,20 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _dense_blocks(K, N):
-    """The padding and blocks `ops.masked_dense` launches with."""
+def _dense_blocks(K, N, kernel):
+    """The padding and blocks `ops.masked_dense` launches `kernel` with
+    at M tokens: the forward and dx from `ops.dense_plan`, ds at
+    bm=128."""
+    Kp, Np = ops._round_up(K, 128), ops._round_up(N, 128)
+    plan = ops.dense_plan(M, K, N)
+    kw = dict(bm=plan.bm, bn=plan.bn, bk=plan.bk, interpret=False)
+    if kernel == "ds":
+        return Kp, Np, dict(kw, bm=128)
+    return Kp, Np, dict(kw, n_logical=N)
+
+
+def _grouped_blocks(K, N):
+    """The padding and blocks `ops.masked_dense_grouped` launches with."""
     Kp, Np = ops._round_up(K, 128), ops._round_up(N, 128)
     return Kp, Np, dict(bm=128, bn=ops._block_for(Np),
                         bk=ops._block_for(Kp), n_logical=N,
@@ -74,7 +86,7 @@ bf, f32, u32 = jnp.bfloat16, jnp.float32, jnp.uint32
 @pytest.mark.parametrize("kernel", ["fwd", "dx", "ds"])
 def test_masked_matmul_compiles(one_chip, kernel, leaf):
     K, N = _LEAVES[leaf]
-    Kp, Np, kw = _dense_blocks(K, N)
+    Kp, Np, kw = _dense_blocks(K, N, kernel)
     w = [((Kp, Np), bf), ((Kp, Np), f32)]
     if kernel == "fwd":
         c = _compile(lambda x, w, s, sd, o: _mm.masked_matmul(
@@ -85,7 +97,6 @@ def test_masked_matmul_compiles(one_chip, kernel, leaf):
             g, w, s, sd, o, **kw), one_chip, ((M, Np), bf), *w,
             ((), u32), ((), u32))
     else:
-        del kw["n_logical"]
         c = _compile(lambda x, g, w, s: _mm.masked_matmul_ds(
             x, g, w, s, **kw), one_chip, ((M, Kp), bf), ((M, Np), bf),
             *w)
@@ -114,7 +125,7 @@ def test_bitpack_compiles(one_chip, kernel):
 @pytest.mark.parametrize("kernel", ["fwd", "dx", "ds"])
 def test_grouped_compiles(one_chip, kernel):
     """One deepseek-v2-lite expert projection: E x (2048 -> 1408)."""
-    Kp, Np, kw = _dense_blocks(DE, FE)
+    Kp, Np, kw = _grouped_blocks(DE, FE)
     Me = 128                                   # tokens per expert
     w = [((E, Kp, Np), bf), ((E, Kp, Np), f32)]
     if kernel == "fwd":
