@@ -299,21 +299,28 @@ def bench_grouped_shape(label, E, M, K, N, iters, warmup, key):
     g = jax.random.normal(kg, (E, M, N), jnp.float32).astype(jnp.bfloat16)
     seeds = jnp.full((E,), 7, jnp.uint32)
     offs = jnp.arange(E, dtype=jnp.uint32) * jnp.uint32(K * N)
+    # E equal groups of M rows in the grouped kernels' row layout
+    x, tg, nl = ref.uniform_groups(x)
+    g, _, _ = ref.uniform_groups(g)
+    lay = ops.GroupLayout(tg, nl, jnp.arange(E, dtype=jnp.int32)
+                          * (x.shape[0] // E))
 
-    fwd = jax.jit(lambda x, w, s: ops.masked_dense_grouped(x, w, s, 7))
-    fwd_ref = jax.jit(
-        lambda x, w, s: ref.masked_matmul_grouped(x, w, s, seeds, offs))
+    fwd = jax.jit(lambda x, w, s: ops.masked_dense_grouped(x, w, s, 7,
+                                                           offs, lay))
+    fwd_ref = jax.jit(lambda x, w, s: ref.masked_matmul_grouped(
+        x, w, s, seeds, offs, tg, nl))
 
     def _bwd(x, w, s, g):
-        _, vjp = jax.vjp(
-            lambda x_, s_: ops.masked_dense_grouped(x_, w, s_, 7), x, s)
+        _, vjp = jax.vjp(lambda x_, s_: ops.masked_dense_grouped(
+            x_, w, s_, 7, offs, lay), x, s)
         return vjp(g)
 
     bwd = jax.jit(_bwd)
 
     def _bwd_ref(x, w, s, g):
-        y = ref.masked_matmul_grouped(x, w, s, seeds, offs)
-        dx, ds = ref.masked_dense_grouped_bwd(x, w, s, seeds, offs, g)
+        y = ref.masked_matmul_grouped(x, w, s, seeds, offs, tg, nl)
+        dx, ds = ref.masked_dense_grouped_bwd(x, w, s, seeds, offs, g,
+                                              tg, nl)
         return y, dx, ds
 
     bwd_ref = jax.jit(_bwd_ref)

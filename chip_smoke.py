@@ -15,7 +15,10 @@ One chip, in one process:
       finite, `uplink_bpp` in (0, 1], the measured bits > 0, and the
       compiled train step must hold Pallas kernels (`tpu_custom_call`).
       Prints each masked projection's block plan (`ops.dense_plan`):
-      `passes` is how often the forward and dx stream `w` and `s`.
+      `passes` is how often the forward and dx stream `w` and `s`; and
+      the grouped kernels' plan (`ops.grouped_plan`) at the
+      deepseek-v2-lite share cell's expert shapes: row block, static
+      tile bound, live tiles at the mean load.
   (b) `sample_and_pack` words of one 2048 x 8192 leaf at C=2 must EQUAL
       the pure-jnp oracle `kernels.ref.sample_and_pack`.
   (c) `ops.masked_dense` forward, dx and ds at that leaf against the jnp
@@ -141,6 +144,21 @@ def projection_passes(jax) -> None:
               f"{plan.bk}, passes {plan.passes}")
 
 
+def grouped_plans() -> None:
+    """The grouped kernels' blocks at the deepseek-v2-lite share cell
+    (seq 8192, top-6 of 64 router experts, 8 held, experts 2048 <->
+    1408): `tiles` bounds the row tiles of the routed buffer, `live`
+    is how many hold rows at the mean load (each streams its expert's
+    `w` and `s` once)."""
+    from repro.kernels import ops
+    T, k, E, held = 8192, 6, 64, 8
+    for K, N in ((2048, 1408), (1408, 2048)):
+        p = ops.grouped_plan(T * min(k, held), K, N, held, T * k / E)
+        print(f"(a) grouped ({held} x {K} x {N}) at {T} tokens: bm "
+              f"{p.bm}, bn {p.bn}, bk {p.bk}, tiles {p.tiles}, live "
+              f"{p.live} ({p.live / held:g} passes an expert)")
+
+
 def phase_pack(jax, seed: int) -> None:
     import jax.numpy as jnp
     from repro.kernels import ops, ref
@@ -248,6 +266,7 @@ def run_one_chip(jax, seed: int) -> None:
     peak_train = _mem(jax.devices()[0])
     del out["compiled_step"]
     projection_passes(jax)
+    grouped_plans()
     phase_pack(jax, seed)
     phase_dense(jax, seed)
     phase_serve(seed)
@@ -307,10 +326,11 @@ def run_four_chips(jax, seed: int) -> None:
     jax.block_until_ready(round_step(state))
     print(f"round step wall {time.perf_counter() - t0!r} s")
 
-    # oracle: every shard samples its own block with its own seeds, the
+    # oracle: every shard samples its own block with its own seeds (its
+    # index within the cohort, the pod's global cohort numbers), the
     # pods' words are gathered, theta is their mean
     pos = {d: np.argwhere(mesh.devices == d)[0] for d in mesh.devices.flat}
-    linear = {d: int(np.ravel_multi_index(p, mesh.devices.shape))
+    linear = {d: int(np.ravel_multi_index(p[1:], mesh.devices.shape[1:]))
               for d, p in pos.items()}
     outs = jax.tree_util.tree_leaves(new["scores"],
                                      is_leaf=lambda x: x is None)
@@ -323,7 +343,8 @@ def run_four_chips(jax, seed: int) -> None:
         for d, sh in shards.items():
             block = sh.data
             seeds = steplib._mask_stream_seeds(
-                step_no, linear[d], i, block.shape[0], run_seed=scfg.seed)
+                step_no, linear[d], i, block.shape[0], run_seed=scfg.seed,
+                first=pos[d][0] * block.shape[0])
             words[d] = np.asarray(jax.jit(ref.sample_and_pack)(
                 block.reshape(block.shape[0], -1), seeds))
             n_words += words[d].size
@@ -352,7 +373,7 @@ def run_four_chips(jax, seed: int) -> None:
         for d, sh in shards.items():
             seeds = steplib._mask_stream_seeds(
                 step_no, linear[d], i, sh.data.shape[0],
-                run_seed=scfg.seed)
+                run_seed=scfg.seed, first=pos[d][0] * sh.data.shape[0])
             kw = np.asarray(jax.jit(ops.sample_and_pack)(
                 sh.data.reshape(sh.data.shape[0], -1), seeds))
             require(np.array_equal(kw, words[d]),

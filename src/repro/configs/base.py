@@ -22,6 +22,13 @@ class ArchConfig:
     global_every: int = 0       # gemma3: 1 global layer per this many (0=all global)
     rope_theta: float = 10000.0
     rope_theta_global: float = 0.0          # gemma3 global layers (0 = same)
+    # YaRN rotary scaling (deepseek-v2 `rope_scaling`; factor 0 = none)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     qkv_bias: bool = False
     attn_soft_cap: Optional[float] = None
 
@@ -38,7 +45,8 @@ class ArchConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     first_dense_layers: int = 0
-    capacity_factor: float = 1.25
+    n_held_experts: int = 0     # experts [0, n) held here (0 = all);
+    #                             the router keeps all n_experts outputs
 
     # SSM (mamba2)
     ssm_state: int = 0
@@ -61,7 +69,8 @@ class ArchConfig:
     # misc
     scan_unroll: int = 1    # lax.scan unroll for layer stacks (roofline)
     remat: bool = False     # activation-checkpoint each layer block
-    moe_block_dispatch: int = 0  # >0: G-block-local MoE dispatch (perf)
+    attn_chunk: int = 0     # KV chunk of attention when the step sets
+    #                         none (0 = the whole sequence at once)
     window_kv_cache: bool = False  # ring-buffer cache for local layers
     logit_sharding: tuple = ()   # with_sharding_constraint spec for logits
     act: str = "silu"
@@ -71,6 +80,10 @@ class ArchConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def n_held(self) -> int:
+        return self.n_held_experts or self.n_experts
 
     def param_count(self) -> int:
         """Approximate total parameter count N (for 6ND roofline math)."""

@@ -9,6 +9,8 @@ CONFIG = ArchConfig(
     v_head_dim=128,
     n_experts=160, n_shared_experts=2, top_k=6, moe_d_ff=1536,
     first_dense_layers=1,
+    yarn_factor=40.0, yarn_original_max_pos=4096, yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0, yarn_mscale=0.707, yarn_mscale_all_dim=0.707,
 )
 
 SMOKE = ArchConfig(
@@ -18,4 +20,6 @@ SMOKE = ArchConfig(
     v_head_dim=16,
     n_experts=8, n_shared_experts=1, top_k=2, moe_d_ff=32,
     first_dense_layers=1,
+    yarn_factor=40.0, yarn_original_max_pos=4096, yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0, yarn_mscale=0.707, yarn_mscale_all_dim=0.707,
 )

@@ -27,9 +27,12 @@ and a fused uplink sampler —
 
 The GROUPED family extends the same discipline to stacked (E, K, N)
 leaves (MoE expert weights): `masked_matmul_grouped` (+ dx/ds) runs one
-pallas_call for all E groups — the expert index rides the grid and each
-group carries its own `seed`/`off` scalar operands, so group e's mask
-is drawn at flat offset e*K*N of the leaf's uplink stream.  The CONV
+pallas_call over the group-contiguous rows of all E groups (groups of
+varying length, each padded to whole row tiles; a tile->group map and
+the live tile count are scalar-prefetch operands, and tiles past the
+live ones do no work), and each group carries its own `seed`/`off`
+scalar operands, so group e's mask is drawn at flat offset e*K*N of
+the leaf's uplink stream.  The CONV
 family (`masked_conv1d`, `masked_conv1d_ds`) covers the depthwise
 causal (W, C) kernel leaves (mamba2 / recurrentgemma frontends), where
 the W-tap reduction is elementwise per channel and unrolled in-kernel;
@@ -69,7 +72,9 @@ buffered activation, output, `w` and `s` blocks, the f32 accumulator
 and the body's f32 temporaries, 12*bm*(bk+bn) + 24*bk*bn bytes, 18 MiB
 at bm = 1024, bk = bn = 512), and compiles the kernels with that
 budget as their scoped VMEM limit.  The ds kernel's grid puts the
-token axis innermost, so it reads each tile once at bm = 128.
+token axis innermost, so it reads each tile once at bm = 128.  The
+grouped kernels take their blocks from `ops.grouped_plan` (bk or bn may
+be a whole padded dim up to 1536) under the same limit.
 """
 from __future__ import annotations
 
@@ -436,15 +441,21 @@ def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 512,
 
 
 # ---------------------------------------------------------------------------
-# Grouped masked matmul: y[e] = x[e] @ (m[e] ⊙ w[e]) for stacked weights
+# Grouped masked matmul: y[r] = x[r] @ (m[e] ⊙ w[e]) over groups of rows
 # ---------------------------------------------------------------------------
 #
-# The group/expert index rides the grid (leading axis, block size 1) and
-# each group carries its OWN `seed`/`off` scalar operand, so group e's
-# mask is exactly its slice of the stacked leaf's flat hash stream
-# (off[e] = e*K*N under the `MaskedLeaf.build` convention).  This is how
-# MoE expert einsums ride the zero-weight-temporary invariant: one
-# pallas_call for all E experts, no (E, K, N) m⊙w tensor in HBM.
+# The rows of all groups sit in one buffer, group-contiguous, each group
+# padded to whole row tiles of `bm` (MoE: the (token, slot) pairs routed
+# to each held expert, sorted by expert).  Two scalar-prefetch operands
+# describe the layout: `tile_group[t]`, the group of row tile t, and
+# `n_live`, how many tiles hold a group's rows; the buffer's other
+# tiles are dead.  A dead tile issues no MXU work and fetches nothing:
+# every index map clamps it to the last live tile's final block, so the
+# pipeline sees an unchanged block index, and its output rows are never
+# written.  Group e's mask is drawn at flat index offs[e] + row*n_total
+# + col of seeds[e]'s stream (offs[e] = e*K*N under the
+# `MaskedLeaf.build` convention): one pallas_call for all groups, no
+# (E, K, N) m⊙w tensor in HBM.
 
 
 def _grp_operands(seeds, offs, tau):
@@ -453,193 +464,261 @@ def _grp_operands(seeds, offs, tau):
             jnp.asarray(tau, jnp.float32).reshape(1, 1))
 
 
-def _g_kernel(x_ref, w_ref, s_ref, seed_ref, off_ref, tau_ref, o_ref,
-              acc_ref, *, bk: int, bn: int, n_total: int, nk: int,
-              mode: str):
-    k_i = pl.program_id(3)
+def _layout_operands(tile_group, n_live):
+    return (jnp.asarray(tile_group, jnp.int32).reshape(-1),
+            jnp.asarray(n_live, jnp.int32).reshape(1))
 
-    @pl.when(k_i == 0)
+
+def _live_clamp(t, nl, *inner):
+    """(live, clamped tile, clamped inner indices): a dead tile maps to
+    the last live tile and each inner index to its last block."""
+    live = t < nl[0]
+    tc = jnp.minimum(t, nl[0] - 1)
+    return live, tc, tuple(jnp.where(live, i, n - 1) for i, n in inner)
+
+
+def _g_kernel(tg_ref, nl_ref, x_ref, w_ref, s_ref, seed_ref, off_ref,
+              tau_ref, o_ref, acc_ref, *, bk: int, bn: int, n_total: int,
+              nk: int, mode: str):
+    t, n_i, k_i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(t < nl_ref[0])
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        @pl.when(k_i == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    e, n_i = pl.program_id(0), pl.program_id(2)
-    m = _tile_mask_vals(s_ref[0], seed_ref[0, e], off_ref[0, e],
-                        tau_ref[0, 0],
-                        row0=k_i * jnp.uint32(bk),
-                        col0=n_i * jnp.uint32(bn),
-                        n_total=n_total, mode=mode)
-    wm = jnp.where(m, w_ref[0].astype(jnp.float32), 0.0)
-    acc_ref[...] += jnp.dot(x_ref[0].astype(jnp.float32), wm,
-                            preferred_element_type=jnp.float32)
+        e = tg_ref[t]
+        m = _tile_mask_vals(s_ref[...], seed_ref[0, e], off_ref[0, e],
+                            tau_ref[0, 0],
+                            row0=k_i * jnp.uint32(bk),
+                            col0=n_i * jnp.uint32(bn),
+                            n_total=n_total, mode=mode)
+        wm = jnp.where(m, w_ref[...].astype(jnp.float32), 0.0)
+        acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), wm,
+                                preferred_element_type=jnp.float32)
 
-    @pl.when(k_i == nk - 1)
-    def _():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)[None]
+        @pl.when(k_i == nk - 1)
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _grouped_call(kernel, name, grid, in_specs, out_spec, out_shape,
+                  scratch, interpret, operands):
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_spec, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=_DENSE_PARAMS,
+        interpret=interpret,
+    )(*operands)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
                                              "n_logical", "interpret",
                                              "mode"))
 def masked_matmul_grouped(x: jax.Array, w: jax.Array, s: jax.Array,
-                          seeds: jax.Array, offs: jax.Array, *,
+                          seeds: jax.Array, offs: jax.Array,
+                          tile_group: jax.Array, n_live: jax.Array, *,
                           bm: int = 128, bn: int = 512, bk: int = 512,
                           n_logical: int | None = None,
                           interpret: bool = False, mode: str = "sample",
                           tau: jax.Array = 0.5) -> jax.Array:
-    """x: (E, M, K); w, s: (E, K, N); seeds, offs: (E,) uint32 per-group
-    hash-stream coordinates.  Returns (E, M, N) in x.dtype: one
-    pallas_call computing y[e] = x[e] @ (m[e] ⊙ w[e]) with group e's
-    mask drawn at flat index offs[e] + row*n_total + col — exactly the
-    slice `sample_and_pack` packs for the stacked leaf when
-    offs[e] = e*K*N.  `mode="threshold"` as in `masked_matmul`."""
-    E, M, K = x.shape
-    assert w.shape == (E, K, s.shape[-1]) and s.shape == w.shape, \
-        (x.shape, w.shape, s.shape)
-    N = w.shape[-1]
+    """x: (R, K) group-contiguous rows, R = tiles * bm; w, s: (E, K, N);
+    seeds, offs: (E,) uint32 per-group stream coordinates; tile_group:
+    (tiles,) int32; n_live: scalar int32.  Returns (R, N) in x.dtype:
+    y[r] = x[r] @ (m[e] ⊙ w[e]) for the rows of live tiles (e the
+    tile's group); dead tiles' rows are left unwritten.
+    `mode="threshold"` as in `masked_matmul`."""
+    R, K = x.shape
+    E, K2, N = w.shape
+    assert K == K2 and s.shape == w.shape, (x.shape, w.shape, s.shape)
+    tiles = tile_group.shape[0]
+    assert R == tiles * bm, (R, tiles, bm)
     n_total = N if n_logical is None else n_logical
-    bm_, bn_, bk_ = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm_ == 0 and N % bn_ == 0 and K % bk_ == 0, \
-        (M, N, K, bm_, bn_, bk_)
-    nm, nn, nk = M // bm_, N // bn_, K // bk_
+    bn_, bk_ = min(bn, N), min(bk, K)
+    assert N % bn_ == 0 and K % bk_ == 0, (N, K, bn_, bk_)
+    nn, nk = N // bn_, K // bk_
+
+    def x_map(t, j, k, tg, nl):
+        _, tc, (kc,) = _live_clamp(t, nl, (k, nk))
+        return tc, kc
+
+    def ws_map(t, j, k, tg, nl):
+        _, tc, (jc, kc) = _live_clamp(t, nl, (j, nn), (k, nk))
+        return tg[tc], kc, jc
+
+    def o_map(t, j, k, tg, nl):
+        _, tc, (jc,) = _live_clamp(t, nl, (j, nn))
+        return tc, jc
 
     kernel = functools.partial(_g_kernel, bk=bk_, bn=bn_,
                                n_total=n_total, nk=nk, mode=mode)
-    return pl.pallas_call(
-        kernel,
-        name="masked_matmul_grouped",
-        grid=(E, nm, nn, nk),
-        in_specs=[
-            pl.BlockSpec((1, bm_, bk_), lambda e, i, j, k: (e, i, k)),
-            pl.BlockSpec((1, bk_, bn_), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1, bk_, bn_), lambda e, i, j, k: (e, k, j)),
-        ] + _SCALAR_SPECS,
-        out_specs=pl.BlockSpec((1, bm_, bn_), lambda e, i, j, k: (e, i, j)),
-        out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        interpret=interpret,
-    )(x, w, s, *_grp_operands(seeds, offs, tau))
+    return _grouped_call(
+        kernel, "masked_matmul_grouped", (tiles, nn, nk),
+        [pl.BlockSpec((bm, bk_), x_map),
+         pl.BlockSpec((None, bk_, bn_), ws_map),
+         pl.BlockSpec((None, bk_, bn_), ws_map)] + _SCALAR_SPECS,
+        pl.BlockSpec((bm, bn_), o_map),
+        jax.ShapeDtypeStruct((R, N), x.dtype),
+        [pltpu.VMEM((bm, bn_), jnp.float32)], interpret,
+        _layout_operands(tile_group, n_live)
+        + (x, w, s) + _grp_operands(seeds, offs, tau))
 
 
-def _g_dx_kernel(g_ref, w_ref, s_ref, seed_ref, off_ref, tau_ref, o_ref,
-                 acc_ref, *, bk: int, bn: int, n_total: int, nn: int,
-                 mode: str):
-    n_i = pl.program_id(3)
+def _g_dx_kernel(tg_ref, nl_ref, g_ref, w_ref, s_ref, seed_ref, off_ref,
+                 tau_ref, o_ref, acc_ref, *, bk: int, bn: int,
+                 n_total: int, nn: int, mode: str):
+    t, k_i, n_i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(n_i == 0)
+    @pl.when(t < nl_ref[0])
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        @pl.when(n_i == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    e, k_i = pl.program_id(0), pl.program_id(2)
-    m = _tile_mask_vals(s_ref[0], seed_ref[0, e], off_ref[0, e],
-                        tau_ref[0, 0],
-                        row0=k_i * jnp.uint32(bk),
-                        col0=n_i * jnp.uint32(bn),
-                        n_total=n_total, mode=mode)
-    wm = jnp.where(m, w_ref[0].astype(jnp.float32), 0.0)   # (bk, bn)
-    acc_ref[...] += jax.lax.dot_general(
-        g_ref[0].astype(jnp.float32), wm,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        e = tg_ref[t]
+        m = _tile_mask_vals(s_ref[...], seed_ref[0, e], off_ref[0, e],
+                            tau_ref[0, 0],
+                            row0=k_i * jnp.uint32(bk),
+                            col0=n_i * jnp.uint32(bn),
+                            n_total=n_total, mode=mode)
+        wm = jnp.where(m, w_ref[...].astype(jnp.float32), 0.0)  # (bk, bn)
+        acc_ref[...] += jax.lax.dot_general(
+            g_ref[...].astype(jnp.float32), wm,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(n_i == nn - 1)
-    def _():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)[None]
+        @pl.when(n_i == nn - 1)
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
                                              "n_logical", "interpret",
                                              "mode"))
 def masked_matmul_grouped_dx(g: jax.Array, w: jax.Array, s: jax.Array,
-                             seeds: jax.Array, offs: jax.Array, *,
+                             seeds: jax.Array, offs: jax.Array,
+                             tile_group: jax.Array, n_live: jax.Array, *,
                              bm: int = 128, bn: int = 512,
                              bk: int = 512, n_logical: int | None = None,
                              interpret: bool = False,
                              mode: str = "sample",
                              tau: jax.Array = 0.5) -> jax.Array:
-    """g: (E, M, N) upstream cotangent; w, s: (E, K, N).  Returns
-    dx[e] = g[e] @ (m[e] ⊙ w[e])ᵀ : (E, M, K) in g.dtype, masks
-    bit-identical to the grouped forward's (same per-group stream)."""
-    E, M, N = g.shape
-    K = w.shape[1]
-    assert w.shape == (E, K, N) and s.shape == (E, K, N)
+    """g: (R, N) upstream cotangent rows; w, s: (E, K, N).  Returns
+    dx[r] = g[r] @ (m[e] ⊙ w[e])ᵀ : (R, K) in g.dtype for the rows of
+    live tiles, masks bit-identical to the grouped forward's."""
+    R, N = g.shape
+    E, K, N2 = w.shape
+    assert N == N2 and s.shape == w.shape
+    tiles = tile_group.shape[0]
+    assert R == tiles * bm, (R, tiles, bm)
     n_total = N if n_logical is None else n_logical
-    bm_, bn_, bk_ = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm_ == 0 and N % bn_ == 0 and K % bk_ == 0, \
-        (M, N, K, bm_, bn_, bk_)
-    nm, nk, nn = M // bm_, K // bk_, N // bn_
+    bn_, bk_ = min(bn, N), min(bk, K)
+    assert N % bn_ == 0 and K % bk_ == 0, (N, K, bn_, bk_)
+    nk, nn = K // bk_, N // bn_
+
+    def g_map(t, k, n, tg, nl):
+        _, tc, (nc,) = _live_clamp(t, nl, (n, nn))
+        return tc, nc
+
+    def ws_map(t, k, n, tg, nl):
+        _, tc, (kc, nc) = _live_clamp(t, nl, (k, nk), (n, nn))
+        return tg[tc], kc, nc
+
+    def o_map(t, k, n, tg, nl):
+        _, tc, (kc,) = _live_clamp(t, nl, (k, nk))
+        return tc, kc
 
     kernel = functools.partial(_g_dx_kernel, bk=bk_, bn=bn_,
                                n_total=n_total, nn=nn, mode=mode)
-    return pl.pallas_call(
-        kernel,
-        name="masked_matmul_grouped_dx",
-        grid=(E, nm, nk, nn),
-        in_specs=[
-            pl.BlockSpec((1, bm_, bn_), lambda e, i, k, n: (e, i, n)),
-            pl.BlockSpec((1, bk_, bn_), lambda e, i, k, n: (e, k, n)),
-            pl.BlockSpec((1, bk_, bn_), lambda e, i, k, n: (e, k, n)),
-        ] + _SCALAR_SPECS,
-        out_specs=pl.BlockSpec((1, bm_, bk_), lambda e, i, k, n: (e, i, k)),
-        out_shape=jax.ShapeDtypeStruct((E, M, K), g.dtype),
-        scratch_shapes=[pltpu.VMEM((bm_, bk_), jnp.float32)],
-        interpret=interpret,
-    )(g, w, s, *_grp_operands(seeds, offs, tau))
+    return _grouped_call(
+        kernel, "masked_matmul_grouped_dx", (tiles, nk, nn),
+        [pl.BlockSpec((bm, bn_), g_map),
+         pl.BlockSpec((None, bk_, bn_), ws_map),
+         pl.BlockSpec((None, bk_, bn_), ws_map)] + _SCALAR_SPECS,
+        pl.BlockSpec((bm, bk_), o_map),
+        jax.ShapeDtypeStruct((R, K), g.dtype),
+        [pltpu.VMEM((bm, bk_), jnp.float32)], interpret,
+        _layout_operands(tile_group, n_live)
+        + (g, w, s) + _grp_operands(seeds, offs, tau))
 
 
-def _g_ds_kernel(x_ref, g_ref, w_ref, s_ref, o_ref, acc_ref, *,
-                 nm: int):
-    m_i = pl.program_id(3)
+def _g_ds_kernel(tg_ref, nl_ref, x_ref, g_ref, w_ref, s_ref, o_ref,
+                 acc_ref, *, tiles: int):
+    t = pl.program_id(2)
+    nl = nl_ref[0]
+    e = tg_ref[t]
+    first = (t == 0) | (tg_ref[jnp.maximum(t - 1, 0)] != e)
+    last = (t == nl - 1) | (tg_ref[jnp.minimum(t + 1, tiles - 1)] != e)
 
-    @pl.when(m_i == 0)
+    @pl.when(t < nl)
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        @pl.when(first)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[0].astype(jnp.float32), g_ref[0].astype(jnp.float32),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        # contract over the rows: (bm, bk) x (bm, bn) -> (bk, bn)
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...].astype(jnp.float32), g_ref[...].astype(jnp.float32),
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(m_i == nm - 1)
-    def _():
-        sig = jax.nn.sigmoid(s_ref[0].astype(jnp.float32))
-        o_ref[...] = (acc_ref[...] * w_ref[0].astype(jnp.float32)
-                      * sig * (1.0 - sig)).astype(o_ref.dtype)[None]
+        @pl.when(last)
+        def _():
+            sig = jax.nn.sigmoid(s_ref[...].astype(jnp.float32))
+            o_ref[...] = (acc_ref[...] * w_ref[...].astype(jnp.float32)
+                          * sig * (1.0 - sig)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
                                              "interpret"))
 def masked_matmul_grouped_ds(x: jax.Array, g: jax.Array, w: jax.Array,
-                             s: jax.Array, *, bm: int = 128,
+                             s: jax.Array, tile_group: jax.Array,
+                             n_live: jax.Array, *, bm: int = 128,
                              bn: int = 512, bk: int = 512,
                              interpret: bool = False) -> jax.Array:
-    """x: (E, M, K); g: (E, M, N); w, s: (E, K, N).  Returns the STE
-    score gradient ds[e] = (x[e]ᵀ@g[e]) ⊙ w[e] ⊙ σ(s[e])(1−σ(s[e])) :
-    (E, K, N) in s.dtype, epilogue fused in VMEM per group."""
-    E, M, K = x.shape
+    """x: (R, K), g: (R, N) group-contiguous rows; w, s: (E, K, N).
+    Returns the STE score gradient ds[e] = (x_eᵀ@g_e) ⊙ w[e] ⊙
+    σ(s[e])(1−σ(s[e])) : (E, K, N) in s.dtype, x_e and g_e the rows of
+    group e's tiles.  The row tiles run innermost, so each (k, n) block
+    of a group accumulates over its tiles and is written once, after
+    its last; every group owns at least one tile (`ops.group_layout`),
+    so every block is written."""
+    R, K = x.shape
     N = g.shape[-1]
-    assert g.shape == (E, M, N) and w.shape == (E, K, N) \
+    E = w.shape[0]
+    assert g.shape == (R, N) and w.shape == (E, K, N) \
         and s.shape == (E, K, N)
-    bm_, bn_, bk_ = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm_ == 0 and N % bn_ == 0 and K % bk_ == 0, \
-        (M, N, K, bm_, bn_, bk_)
-    nk, nn, nm = K // bk_, N // bn_, M // bm_
+    tiles = tile_group.shape[0]
+    assert R == tiles * bm, (R, tiles, bm)
+    bn_, bk_ = min(bn, N), min(bk, K)
+    assert N % bn_ == 0 and K % bk_ == 0, (N, K, bn_, bk_)
+    nk, nn = K // bk_, N // bn_
 
-    kernel = functools.partial(_g_ds_kernel, nm=nm)
-    return pl.pallas_call(
-        kernel,
-        name="masked_matmul_grouped_ds",
-        grid=(E, nk, nn, nm),
-        in_specs=[
-            pl.BlockSpec((1, bm_, bk_), lambda e, k, n, m: (e, m, k)),
-            pl.BlockSpec((1, bm_, bn_), lambda e, k, n, m: (e, m, n)),
-            pl.BlockSpec((1, bk_, bn_), lambda e, k, n, m: (e, k, n)),
-            pl.BlockSpec((1, bk_, bn_), lambda e, k, n, m: (e, k, n)),
-        ],
-        out_specs=pl.BlockSpec((1, bk_, bn_), lambda e, k, n, m: (e, k, n)),
-        out_shape=jax.ShapeDtypeStruct((E, K, N), s.dtype),
-        scratch_shapes=[pltpu.VMEM((bk_, bn_), jnp.float32)],
-        interpret=interpret,
-    )(x, g, w, s)
+    def x_map(k, n, t, tg, nl):
+        return jnp.minimum(t, nl[0] - 1), k
+
+    def g_map(k, n, t, tg, nl):
+        return jnp.minimum(t, nl[0] - 1), n
+
+    def ws_map(k, n, t, tg, nl):
+        return tg[jnp.minimum(t, nl[0] - 1)], k, n
+
+    kernel = functools.partial(_g_ds_kernel, tiles=tiles)
+    return _grouped_call(
+        kernel, "masked_matmul_grouped_ds", (nk, nn, tiles),
+        [pl.BlockSpec((bm, bk_), x_map),
+         pl.BlockSpec((bm, bn_), g_map),
+         pl.BlockSpec((None, bk_, bn_), ws_map),
+         pl.BlockSpec((None, bk_, bn_), ws_map)],
+        pl.BlockSpec((None, bk_, bn_), ws_map),
+        jax.ShapeDtypeStruct((E, K, N), s.dtype),
+        [pltpu.VMEM((bk_, bn_), jnp.float32)], interpret,
+        _layout_operands(tile_group, n_live) + (x, g, w, s))
 
 
 # ---------------------------------------------------------------------------

@@ -21,9 +21,11 @@ m = 1[sigmoid(s) > tau] (no hash), same STE backward, same fusion.
 
 `masked_dense_grouped` (+ `_threshold`) is the stacked-leaf twin for
 (E, K, N) MoE expert weights: ONE grouped pallas_call per projection
-covers all E experts with per-group seed/off stream coordinates
-(offs[e] = e*K*N under the `MaskedLeaf.build` convention), so the
-stacked m⊙w never exists in HBM either.  `masked_conv1d`
+covers the group-contiguous rows of all E experts (groups of varying
+length, laid out by `group_layout`, blocked by `grouped_plan`) with
+per-group seed/off stream coordinates (offs[e] = e*K*N under the
+`MaskedLeaf.build` convention), so the stacked m⊙w never exists in
+HBM either.  `masked_conv1d`
 (+ `_threshold`) covers the depthwise causal (W, C) conv kernel leaves,
 and `conv1d_plain` is its mask-free twin for pre-materialized weights —
 the reference path runs it so fused and materialized convs are
@@ -281,124 +283,191 @@ def masked_dense_threshold(x, w, s, tau=0.5):
 
 
 # ---------------------------------------------------------------------------
-# Grouped masked dense: stacked (E, K, N) weights, one kernel launch
+# Grouped masked dense: stacked (E, K, N) weights over groups of rows
 # ---------------------------------------------------------------------------
 
 
-def _pad3(a: jax.Array, m: int, k: int) -> jax.Array:
-    pm, pk = m - a.shape[1], k - a.shape[2]
-    if pm == 0 and pk == 0:
+class GroupLayout(NamedTuple):
+    """Where each group's rows sit in a group-contiguous row buffer of
+    `tiles` row tiles.  Group e owns max(1, ceil(size_e / bm)) whole
+    tiles from row `starts[e]`, in group order; the tiles past `n_live`
+    belong to no group (their `tile_group` is the last group's, so a
+    kernel clamping to the last live tile sees no change)."""
+    tile_group: jax.Array   # (tiles,) int32
+    n_live: jax.Array       # (1,) int32
+    starts: jax.Array       # (E,) int32
+
+
+def group_layout(sizes: jax.Array, bm: int, tiles: int) -> GroupLayout:
+    """The layout of groups of `sizes` (E,) rows in row tiles of `bm`.
+    A buffer of `tiles` tiles holds it whenever tiles >=
+    sum(sizes) // bm + E (the bound `grouped_plan` gives): each group
+    rounds its rows up by less than one tile, an empty one to one."""
+    E = sizes.shape[0]
+    nt = jnp.maximum(1, -(-sizes.astype(jnp.int32) // bm))
+    ends = jnp.cumsum(nt)
+    t = jnp.arange(tiles, dtype=jnp.int32)
+    tg = jnp.minimum(jnp.sum(ends[None, :] <= t[:, None], axis=1), E - 1)
+    return GroupLayout(tg.astype(jnp.int32), ends[-1:].astype(jnp.int32),
+                       ((ends - nt) * bm).astype(jnp.int32))
+
+
+class GroupedPlan(NamedTuple):
+    """Blocks of one grouped launch over a buffer of `tiles` row tiles
+    of `bm` rows; `live` is how many of them hold rows when every group
+    holds the mean load: each live tile streams its group's (K, N)
+    tiles of `w` and `s` once, so live / groups is the grouped twin of
+    `DensePlan.passes`."""
+    bm: int
+    bn: int
+    bk: int
+    tiles: int
+    live: int
+
+
+def _grouped_block(dp: int) -> int:
+    """512 where it divides the padded dim, else the whole padded dim up
+    to 1536 (an expert width of 1408 = 11 x 128 then takes one block,
+    not eleven), else `_block_for`."""
+    if dp % 512 and dp <= 1536:
+        return dp
+    return _block_for(dp)
+
+
+def grouped_plan(rows: int, K: int, N: int, groups: int,
+                 mean: float) -> GroupedPlan:
+    """Blocks for up to `rows` routed rows in `groups` groups of `mean`
+    rows on average, through (K, N) expert weights.  bm is the largest
+    of 512, 256, 128 that is not above the mean load (128 at least) and
+    whose working set fits `masked_matmul.VMEM_BUDGET`: a group pads by
+    less than a tile, and a tile of `w` and `s` streams once per live
+    tile.  The static tile bound is rows // bm + groups."""
+    bk, bn = _grouped_block(_round_up(K, 128)), \
+        _grouped_block(_round_up(N, 128))
+    fits = [b for b in (512, 256) if b <= mean
+            and _dense_vmem_bytes(b, bn, bk) <= _mm.VMEM_BUDGET]
+    bm = fits[0] if fits else 128
+    live = groups * max(1, -(-int(mean) // bm))
+    return GroupedPlan(bm, bn, bk, rows // bm + groups, live)
+
+
+def _pad3(a: jax.Array, k: int, n: int) -> jax.Array:
+    """Zero-pad the trailing two axes of a stacked (E, K, N) leaf."""
+    pk, pn = k - a.shape[1], n - a.shape[2]
+    if pk == 0 and pn == 0:
         return a
-    return jnp.pad(a, ((0, 0), (0, pm), (0, pk)))
+    return jnp.pad(a, ((0, 0), (0, pk), (0, pn)))
 
 
-def _grp_fused_fwd(x, w, s, seeds, offs, tau, mode):
-    shape = x.shape
-    E = shape[0]
-    x3 = x.reshape(E, -1, shape[-1])
-    M = x3.shape[1]
+def _grp_blocks(w):
     K, N = w.shape[-2:]
-    Mp, Kp, Np = (_round_up(M, 128), _round_up(K, 128),
-                  _round_up(N, 128))
+    Kp, Np = _round_up(K, 128), _round_up(N, 128)
+    return K, N, Kp, Np, _grouped_block(Kp), _grouped_block(Np)
+
+
+def _grp_fused_fwd(x, w, s, seeds, offs, tg, nl, tau, mode):
+    R = x.shape[0]
+    K, N, Kp, Np, bk, bn = _grp_blocks(w)
     y = _mm.masked_matmul_grouped(
-        _pad3(x3, Mp, Kp), _pad3(w, Kp, Np), _pad3(s, Kp, Np), seeds,
-        offs, bm=128, bn=_block_for(Np), bk=_block_for(Kp), n_logical=N,
-        interpret=_use_interpret(), mode=mode, tau=tau)[:, :M, :N]
-    return y.reshape(shape[:-1] + (N,))
+        _pad2(x, R, Kp), _pad3(w, Kp, Np), _pad3(s, Kp, Np), seeds, offs,
+        tg, nl, bm=R // tg.shape[0], bn=bn, bk=bk, n_logical=N,
+        interpret=_use_interpret(), mode=mode, tau=tau)
+    return y[:, :N]
 
 
-def _grp_fused_bwd(x, w, s, seeds, offs, tau, mode, g):
-    E = x.shape[0]
-    K, N = w.shape[-2:]
+def _grp_fused_bwd(x, w, s, seeds, offs, tg, nl, tau, mode, g):
     if os.environ.get("REPRO_REF_BWD", "") == "1":
-        x3 = x.reshape(E, -1, K)
-        g3 = g.reshape(E, -1, N)
-        dx, ds = ref.masked_dense_grouped_bwd(x3, w, s, seeds, offs, g3,
-                                              mode, tau)
-        return dx.reshape(x.shape).astype(x.dtype), ds
-    x3 = x.reshape(E, -1, K)
-    g3 = g.reshape(E, -1, N)
-    M = x3.shape[1]
-    Mp, Kp, Np = (_round_up(M, 128), _round_up(K, 128),
-                  _round_up(N, 128))
-    bn, bk = _block_for(Np), _block_for(Kp)
+        dx, ds = ref.masked_dense_grouped_bwd(x, w, s, seeds, offs, g, tg,
+                                              nl, mode, tau)
+        return dx.astype(x.dtype), ds
+    R = x.shape[0]
+    K, N, Kp, Np, bk, bn = _grp_blocks(w)
+    bm = R // tg.shape[0]
     interp = _use_interpret()
-    xp, gp = _pad3(x3, Mp, Kp), _pad3(g3, Mp, Np)
+    xp, gp = _pad2(x, R, Kp), _pad2(g, R, Np)
     wp, sp = _pad3(w, Kp, Np), _pad3(s, Kp, Np)
     dx = _mm.masked_matmul_grouped_dx(
-        gp, wp, sp, seeds, offs, bm=128, bn=bn, bk=bk, n_logical=N,
-        interpret=interp, mode=mode, tau=tau)[:, :M, :K]
+        gp, wp, sp, seeds, offs, tg, nl, bm=bm, bn=bn, bk=bk,
+        n_logical=N, interpret=interp, mode=mode, tau=tau)[:, :K]
     ds = _mm.masked_matmul_grouped_ds(
-        xp, gp, wp, sp, bm=128, bn=bn, bk=bk, interpret=interp)[:, :K, :N]
-    return (dx.reshape(x.shape).astype(x.dtype), ds.astype(s.dtype))
+        xp, gp, wp, sp, tg, nl, bm=bm, bn=bn, bk=bk,
+        interpret=interp)[:, :K, :N]
+    return dx.astype(x.dtype), ds.astype(s.dtype)
 
 
 @jax.custom_vjp
-def _masked_dense_grouped(x, w, s, seeds, offs):
-    return _grp_fused_fwd(x, w, s, seeds, offs, 0.5, "sample")
+def _masked_dense_grouped(x, w, s, seeds, offs, tg, nl):
+    return _grp_fused_fwd(x, w, s, seeds, offs, tg, nl, 0.5, "sample")
 
 
-def _mdg_fwd(x, w, s, seeds, offs):
-    return (_masked_dense_grouped(x, w, s, seeds, offs),
-            (x, w, s, seeds, offs))
+def _mdg_fwd(x, w, s, seeds, offs, tg, nl):
+    return (_masked_dense_grouped(x, w, s, seeds, offs, tg, nl),
+            (x, w, s, seeds, offs, tg, nl))
 
 
 def _mdg_bwd(res, g):
-    x, w, s, seeds, offs = res
-    dx, ds = _grp_fused_bwd(x, w, s, seeds, offs, 0.5, "sample", g)
-    return dx, None, ds, None, None
+    x, w, s, seeds, offs, tg, nl = res
+    dx, ds = _grp_fused_bwd(x, w, s, seeds, offs, tg, nl, 0.5, "sample",
+                            g)
+    return dx, None, ds, None, None, None, None
 
 
 _masked_dense_grouped.defvjp(_mdg_fwd, _mdg_bwd)
 
 
 @jax.custom_vjp
-def _masked_dense_grouped_thr(x, w, s, tau):
-    E = x.shape[0]
-    zeros = jnp.zeros((E,), jnp.uint32)
-    return _grp_fused_fwd(x, w, s, zeros, zeros, tau, "threshold")
+def _masked_dense_grouped_thr(x, w, s, tau, tg, nl):
+    zeros = jnp.zeros((w.shape[0],), jnp.uint32)
+    return _grp_fused_fwd(x, w, s, zeros, zeros, tg, nl, tau,
+                          "threshold")
 
 
-def _mdgt_fwd(x, w, s, tau):
-    return _masked_dense_grouped_thr(x, w, s, tau), (x, w, s, tau)
+def _mdgt_fwd(x, w, s, tau, tg, nl):
+    return (_masked_dense_grouped_thr(x, w, s, tau, tg, nl),
+            (x, w, s, tau, tg, nl))
 
 
 def _mdgt_bwd(res, g):
-    x, w, s, tau = res
-    E = x.shape[0]
-    zeros = jnp.zeros((E,), jnp.uint32)
-    dx, ds = _grp_fused_bwd(x, w, s, zeros, zeros, tau, "threshold", g)
-    return dx, None, ds, None
+    x, w, s, tau, tg, nl = res
+    zeros = jnp.zeros((w.shape[0],), jnp.uint32)
+    dx, ds = _grp_fused_bwd(x, w, s, zeros, zeros, tg, nl, tau,
+                            "threshold", g)
+    return dx, None, ds, None, None, None
 
 
 _masked_dense_grouped_thr.defvjp(_mdgt_fwd, _mdgt_bwd)
 
 
-def masked_dense_grouped(x, w, s, seeds, offs=None):
-    """y[e] = x[e] @ (bern(sigmoid(s[e]); seeds[e], offs[e]) * w[e]) for
-    stacked (E, K, N) weights, STE backward.  x: (E, ..., K).
+def masked_dense_grouped(x, w, s, seeds, offs, layout: GroupLayout):
+    """y[r] = x[r] @ (bern(sigmoid(s[e]); seeds[e], offs[e]) * w[e]) for
+    the rows r of group e, STE backward.  x: (tiles * bm, K) rows laid
+    out by `layout` (`group_layout`); w, s: (E, K, N).  Rows of dead
+    tiles come back unwritten, and their cotangents are never read.
 
-    One `pallas_call` covers all E groups (the expert index rides the
-    grid) with per-group `seeds`/`offs` stream coordinates: under the
-    `MaskedLeaf.build` convention (offs[e] = e*K*N, one seed) the E
-    masks together are exactly the stacked leaf's flat
-    `sample_and_pack` stream.  MXU-unaligned M/K/N are zero-padded with
-    the hash indexed by the logical column count, as in `masked_dense`.
+    One `pallas_call` covers all E groups with per-group `seeds`/`offs`
+    stream coordinates (offs None: e*K*N, the `MaskedLeaf.build`
+    convention), so the E masks together are exactly the stacked
+    leaf's flat `sample_and_pack` stream.  MXU-unaligned K/N are
+    zero-padded with the hash indexed by the logical column count, as
+    in `masked_dense`.
     """
-    E = x.shape[0]
+    E = w.shape[0]
     seeds = jnp.broadcast_to(jnp.asarray(seeds, jnp.uint32), (E,))
     if offs is None:
         K, N = w.shape[-2:]
         offs = jnp.arange(E, dtype=jnp.uint32) * jnp.uint32(K * N)
     offs = jnp.broadcast_to(jnp.asarray(offs, jnp.uint32), (E,))
-    return _masked_dense_grouped(x, w, s, seeds, offs)
+    return _masked_dense_grouped(x, w, s, seeds, offs, layout.tile_group,
+                                 layout.n_live)
 
 
-def masked_dense_grouped_threshold(x, w, s, tau=0.5):
-    """y[e] = x[e] @ (1[sigmoid(s[e]) > tau] * w[e]) for stacked
-    (E, K, N) weights, STE backward (FedMask mode; no hash stream)."""
+def masked_dense_grouped_threshold(x, w, s, layout: GroupLayout,
+                                   tau=0.5):
+    """`masked_dense_grouped` with m = 1[sigmoid(s[e]) > tau] (FedMask
+    mode; no hash stream)."""
     return _masked_dense_grouped_thr(x, w, s,
-                                     jnp.asarray(tau, jnp.float32))
+                                     jnp.asarray(tau, jnp.float32),
+                                     layout.tile_group, layout.n_live)
 
 
 # ---------------------------------------------------------------------------

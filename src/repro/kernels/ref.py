@@ -81,43 +81,82 @@ def _grouped_mask(s, seeds, offs, mode="sample", tau=0.5):
                                  jnp.asarray(offs, jnp.uint32))
 
 
-def masked_matmul_grouped(x, w, s, seeds, offs, mode="sample", tau=0.5):
-    """Oracle for kernels.masked_matmul_grouped: y[e] = x[e] @ (m[e]⊙w[e])
-    with group e's mask drawn at flat offset offs[e] of seeds[e]'s
-    stream (offs[e] = e*K*N makes the E masks one stacked-leaf stream)."""
+def uniform_groups(x3, bm: int = 128):
+    """E equal groups (E, M, K) in the grouped kernels' row layout:
+    each group's M rows padded with zeros to whole tiles of `bm`.
+    Returns (rows (E*t*bm, K), tile_group (E*t,), n_live) with
+    t = ceil(M / bm); `ungroup` takes rows back to (E, M, .)."""
+    E, M, K = x3.shape
+    t = -(-M // bm)
+    rows = jnp.pad(x3, ((0, 0), (0, t * bm - M), (0, 0)))
+    return (rows.reshape(E * t * bm, K),
+            jnp.repeat(jnp.arange(E, dtype=jnp.int32), t),
+            jnp.array([E * t], jnp.int32))
+
+
+def ungroup(rows, E: int, M: int):
+    return rows.reshape(E, -1, rows.shape[-1])[:, :M]
+
+
+def _row_groups(tile_group, n_live, R):
+    """(R, E) one-hot of each row's group, zero on dead tiles' rows."""
+    tiles = tile_group.shape[0]
+    grp = jnp.repeat(jnp.asarray(tile_group), R // tiles)
+    live = jnp.repeat(jnp.arange(tiles) < jnp.asarray(n_live).reshape(()),
+                      R // tiles)
+    return grp, live
+
+
+def _grouped_rows(a, wm, tile_group, n_live, eq):
+    R = a.shape[0]
+    grp, live = _row_groups(tile_group, n_live, R)
+    ys = jnp.einsum(eq, a.astype(jnp.float32), wm)      # (E, R, .)
+    y = jnp.take_along_axis(ys, grp[None, :, None], axis=0)[0]
+    return jnp.where(live[:, None], y, 0.0).astype(a.dtype)
+
+
+def masked_matmul_grouped(x, w, s, seeds, offs, tile_group, n_live,
+                          mode="sample", tau=0.5):
+    """Oracle for kernels.masked_matmul_grouped: y[r] = x[r] @
+    (m[e]⊙w[e]) for the rows r of group e (the tile's group in
+    `tile_group`), group e's mask drawn at flat offset offs[e] of
+    seeds[e]'s stream; dead tiles' rows are zero here."""
     wm = _grouped_mask(s, seeds, offs, mode, tau).astype(jnp.float32) \
         * w.astype(jnp.float32)
-    return jnp.einsum("emk,ekn->emn", x.astype(jnp.float32),
-                      wm).astype(x.dtype)
+    return _grouped_rows(x, wm, tile_group, n_live, "rk,ekn->ern")
 
 
-def masked_matmul_grouped_dx(g, w, s, seeds, offs, mode="sample",
-                             tau=0.5):
+def masked_matmul_grouped_dx(g, w, s, seeds, offs, tile_group, n_live,
+                             mode="sample", tau=0.5):
     """Oracle for kernels.masked_matmul_grouped_dx:
-    dx[e] = g[e] @ (m[e] ⊙ w[e])ᵀ, same per-group streams."""
+    dx[r] = g[r] @ (m[e] ⊙ w[e])ᵀ, same per-group streams."""
     wm = _grouped_mask(s, seeds, offs, mode, tau).astype(jnp.float32) \
         * w.astype(jnp.float32)
-    return jnp.einsum("emn,ekn->emk", g.astype(jnp.float32),
-                      wm).astype(g.dtype)
+    return _grouped_rows(g, wm, tile_group, n_live, "rn,ekn->erk")
 
 
-def masked_matmul_grouped_ds(x, g, w, s):
+def masked_matmul_grouped_ds(x, g, w, s, tile_group, n_live):
     """Oracle for kernels.masked_matmul_grouped_ds:
-    ds[e] = (x[e]ᵀ@g[e]) ⊙ w[e] ⊙ σ(s[e])(1−σ(s[e]))."""
-    xg = jnp.einsum("emk,emn->ekn", x.astype(jnp.float32),
-                    g.astype(jnp.float32))
+    ds[e] = (x_eᵀ@g_e) ⊙ w[e] ⊙ σ(s[e])(1−σ(s[e])) over group e's
+    rows."""
+    E = w.shape[0]
+    grp, live = _row_groups(tile_group, n_live, x.shape[0])
+    sel = jax.nn.one_hot(grp, E, dtype=jnp.float32) * live[:, None]
+    xg = jnp.einsum("rk,rn,re->ekn", x.astype(jnp.float32),
+                    g.astype(jnp.float32), sel)
     sig = jax.nn.sigmoid(s.astype(jnp.float32))
     return (xg * w.astype(jnp.float32) * sig * (1.0 - sig)).astype(
         s.dtype)
 
 
-def masked_dense_grouped_bwd(x, w, s, seeds, offs, g, mode="sample",
-                             tau=0.5):
+def masked_dense_grouped_bwd(x, w, s, seeds, offs, g, tile_group, n_live,
+                             mode="sample", tau=0.5):
     """The naive grouped STE backward (REPRO_REF_BWD=1 and the
     benchmark baseline): materializes the stacked mask, m⊙w and xᵀ@g
     at full (E, K, N) size."""
-    dx = masked_matmul_grouped_dx(g, w, s, seeds, offs, mode, tau)
-    ds = masked_matmul_grouped_ds(x, g, w, s)
+    dx = masked_matmul_grouped_dx(g, w, s, seeds, offs, tile_group,
+                                  n_live, mode, tau)
+    ds = masked_matmul_grouped_ds(x, g, w, s, tile_group, n_live)
     return dx, ds
 
 

@@ -30,6 +30,7 @@ class LaunchPlan:
     round_fn: Optional[Callable]      # (state) -> (state, metrics) | None
     #                                   both donate `state`
     make_batch: Callable              # (key, tokens, batch, seq) -> batch
+    shardings: Any = None             # the state's on a mesh, else None
 
 
 def _cohort_batch(cohorts: int):
@@ -53,9 +54,16 @@ def _mask_plan(name, *, force_lam=None, mask_mode=None):
     picks the wire codec the round step meters uplinks with (`--codec`
     in `repro.launch.train`); `mask_mode="threshold"` is the FedMask
     variant — the forward differentiates through the fused threshold
-    kernels and the uplink packs the deterministic mask."""
+    kernels and the uplink packs the deterministic mask.
+
+    With a pod `mesh` ("pod", "data", "model"; data and model 1), the
+    state is placed by `steps.fed_state_shardings` (cohorts on "pod",
+    each chip holding its cohorts' whole leaves), and both steps run
+    under shard_map: the round all-gathers the packed words over
+    "pod"."""
     def plan(model_api, scfg: steplib.StepConfig, *, key, cohorts,
-             spec=None, optimizer="momentum", codec=None) -> LaunchPlan:
+             spec=None, optimizer="momentum", codec=None,
+             mesh=None) -> LaunchPlan:
         if force_lam is not None:
             scfg = dataclasses.replace(scfg, lam=force_lam)
         if mask_mode is not None:
@@ -63,23 +71,31 @@ def _mask_plan(name, *, force_lam=None, mask_mode=None):
         spec = masking.MaskSpec() if spec is None else spec
         state = steplib.init_fed_state(key, model_api, spec, C=cohorts,
                                        optimizer=optimizer)
+        sh = None
+        if mesh is not None:
+            sh = steplib.fed_state_shardings(state, mesh)
+            state = jax.device_put(state, sh)
         # both steps donate the state: the old and the new one are
         # never live together, which is what lets a full-width model
         # fit one chip
+        out = {} if sh is None else {"out_shardings": (sh, None)}
         return LaunchPlan(
             name=name, state=state,
-            step_fn=jax.jit(steplib.make_train_step(model_api, scfg),
-                            donate_argnums=0),
-            round_fn=jax.jit(steplib.make_round_step(model_api, scfg,
-                                                     codec=codec),
-                             donate_argnums=0),
-            make_batch=_cohort_batch(cohorts))
+            step_fn=jax.jit(steplib.make_train_step(
+                model_api, scfg, mesh=mesh, state_sh=sh),
+                donate_argnums=0, **out),
+            round_fn=jax.jit(steplib.make_round_step(
+                model_api, scfg, mesh=mesh, state_sh=sh, codec=codec),
+                donate_argnums=0, **out),
+            make_batch=_cohort_batch(cohorts), shardings=sh)
     return plan
 
 
 def _fedavg_plan(model_api, scfg: steplib.StepConfig, *, key, cohorts,
                  spec=None, optimizer="momentum",
-                 codec=None) -> LaunchPlan:
+                 codec=None, mesh=None) -> LaunchPlan:
+    if mesh is not None:
+        raise ValueError("fedavg has no pod-mesh plan")
     state = steplib.init_fedavg_state(key, model_api)
     return LaunchPlan(
         name="fedavg", state=state,

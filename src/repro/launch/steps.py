@@ -159,14 +159,19 @@ def _eff_path() -> bool:
     return os.environ.get("REPRO_EFF_PATH", "") == "1"
 
 
-def make_train_step(api, cfg: StepConfig):
+def make_train_step(api, cfg: StepConfig, mesh=None, state_sh=None):
     """One local mini-batch score update on the fused masked-execution
     path: the forward consumes a `masked_forward_tree` whose maskable
     leaves run the fused kernels (dense / grouped-expert / conv) with
     scores as a first-class grad argument (STE custom-vjp), per-leaf
     seeds derived from
     (cfg.seed, step, leaf, cohort) by the SAME `mask_stream_seed`
-    convention the round uplink samples with."""
+    convention the round uplink samples with.
+
+    With `mesh`/`state_sh` (a pod mesh whose other axes are 1, so each
+    chip holds whole leaves), the step runs under shard_map: each chip
+    trains its own cohorts with their global cohort numbers, and no
+    traffic crosses chips but the reported loss's mean."""
     def cohort_loss(scores, floats, weights, batch, tick, cohort):
         mp = MaskedParams(weights, scores, floats)
         seed_fn = lambda i: masking.mask_stream_seed(
@@ -183,6 +188,8 @@ def make_train_step(api, cfg: StepConfig):
 
     def train_step(state, batch):
         C = jax.tree_util.tree_leaves(state["scores"])[0].shape[0]
+        first = (cohort_shard(mesh, C)[0] if mesh is not None
+                 else jnp.int32(0))
 
         @jax.named_scope("optimizer")
         def update(scores, floats, opt_m, opt_v, gs, gf):
@@ -278,19 +285,31 @@ def make_train_step(api, cfg: StepConfig):
         if has_v:
             scores, floats, opt_m, opt_v, losses = jax.vmap(one)(
                 state["scores"], state["floats"], state["opt_m"],
-                opt_v_in, batch, jnp.arange(C))
+                opt_v_in, batch, first + jnp.arange(C))
         else:
             scores, floats, opt_m, opt_v, losses = jax.vmap(
                 one, in_axes=(0, 0, 0, None, 0, 0))(
                 state["scores"], state["floats"], state["opt_m"],
-                None, batch, jnp.arange(C))
+                None, batch, first + jnp.arange(C))
         new_state = dict(state, scores=scores, floats=floats, opt_m=opt_m,
                          step=state["step"] + 1)
         if has_v:
             new_state["opt_v"] = opt_v
-        return new_state, {"loss": jnp.mean(losses)}
+        loss = jnp.mean(losses)
+        if mesh is not None and "pod" in mesh.axis_names:
+            loss = jax.lax.pmean(loss, "pod")
+        return new_state, {"loss": loss}
 
-    return train_step
+    if mesh is None:
+        return train_step
+    assert all(mesh.shape[a] == 1 for a in mesh.axis_names if a != "pod"), \
+        f"the train step holds whole leaves per chip: mesh {mesh.shape}"
+    P = jax.sharding.PartitionSpec
+    specs = _specs_of(state_sh)
+    return jax.shard_map(train_step, mesh=mesh,
+                         in_specs=(specs, P("pod")),
+                         out_specs=(specs, {"loss": P()}),
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -299,15 +318,39 @@ def make_train_step(api, cfg: StepConfig):
 
 
 def _mask_stream_seeds(step, dev, leaf_idx: int, C: int,
-                       run_seed=0) -> jax.Array:
+                       run_seed=0, first=0) -> jax.Array:
     """Per-(run, round, shard, leaf, cohort) uint32 seeds for the
     counter-based mask sampler — one thin wrapper over the SHARED
     convention (`masking.mask_stream_seed`) the fused model forward
     derives its per-leaf seeds with, so a leaf's forward mask and its
-    uplink `sample_and_pack` words come from one stream family."""
-    return masking.mask_stream_seed(step, dev, leaf_idx,
-                                    jnp.arange(C, dtype=jnp.uint32),
-                                    run_seed=run_seed)
+    uplink `sample_and_pack` words come from one stream family.
+    Cohorts are numbered globally from `first`; `dev` is the shard
+    within a cohort."""
+    return masking.mask_stream_seed(
+        step, dev, leaf_idx,
+        jnp.asarray(first, jnp.uint32) + jnp.arange(C, dtype=jnp.uint32),
+        run_seed=run_seed)
+
+
+def cohort_shard(mesh, cohorts_here: int):
+    """(global number of the first of the `cohorts_here` cohorts this
+    shard holds, the shard's index within its cohort) under shard_map
+    over `mesh`: cohorts ride the "pod" axis, and the other axes divide
+    one cohort's state.  So a round on a pod mesh draws each cohort's
+    mask from the stream it draws without one."""
+    shard = jnp.int32(0)
+    for a in mesh.axis_names:
+        if a != "pod":
+            shard = shard * mesh.shape[a] + jax.lax.axis_index(a)
+    pod = (jax.lax.axis_index("pod") if "pod" in mesh.axis_names
+           else jnp.int32(0))
+    return pod * cohorts_here, shard
+
+
+def _specs_of(tree):
+    return jax.tree_util.tree_map(
+        lambda s: None if s is None else s.spec, tree,
+        is_leaf=lambda x: x is None)
 
 
 def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
@@ -355,21 +398,20 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
         paths see bit-identical masks.
         """
         pod_axis = "pod" if has_pod else None
-        if mesh is not None:
-            # distinct hash stream per device shard (same seed would
-            # give identical bits on every shard)
-            dev = jnp.int32(0)
-            for a in mesh.axis_names:
-                dev = dev * mesh.shape[a] + jax.lax.axis_index(a)
-        else:
-            dev = jnp.int32(0)
-
         flat_s, tdef = jax.tree_util.tree_flatten(
             scores, is_leaf=lambda x: x is None)
         # survivor weights: normalized over the GLOBAL participation
         # vector; each shard also needs its local slice (its own
         # cohorts' alive flags) for float folds and metering
         Cl_loc = next((l.shape[0] for l in flat_s if l is not None), 1)
+        if mesh is not None:
+            # distinct hash stream per shard of a cohort (same seed
+            # would give identical bits on every shard), cohorts
+            # numbered globally
+            first, dev = cohort_shard(mesh, Cl_loc)
+        else:
+            first, dev = 0, jnp.int32(0)
+
         if part is not None:
             wn_g = part / jnp.maximum(jnp.sum(part), 1.0)
             if pod_axis:
@@ -397,7 +439,7 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
                 flat = sl.reshape(Cl, -1)
                 n = flat.shape[1]
                 seeds = _mask_stream_seeds(step, dev, i, Cl,
-                                           run_seed=cfg.seed)
+                                           run_seed=cfg.seed, first=first)
                 if cfg.packed_masks:
                     words = aggregation.sample_and_pack_rows(
                         flat, seeds, use_kernel=True,
@@ -584,17 +626,14 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
                 None if part is None else jnp.sum(part))
         return round_step
 
-    def specs_of(tree):
-        return jax.tree_util.tree_map(
-            lambda s: None if s is None else s.spec, tree,
-            is_leaf=lambda x: x is None)
-
-    in_specs = (specs_of(state_sh["scores"]), specs_of(state_sh["floats"]),
-                specs_of(state_sh["weights"]), specs_of(state_sh["opt_m"]),
+    in_specs = (_specs_of(state_sh["scores"]),
+                _specs_of(state_sh["floats"]),
+                _specs_of(state_sh["weights"]),
+                _specs_of(state_sh["opt_m"]),
                 jax.sharding.PartitionSpec())
-    out_specs = (specs_of(state_sh["scores"]),
-                 specs_of(state_sh["floats"]),
-                 specs_of(state_sh["opt_m"]),
+    out_specs = (_specs_of(state_sh["scores"]),
+                 _specs_of(state_sh["floats"]),
+                 _specs_of(state_sh["opt_m"]),
                  jax.sharding.PartitionSpec(),
                  jax.sharding.PartitionSpec())
     mapped = jax.shard_map(_round_local, mesh=mesh, in_specs=in_specs,

@@ -90,6 +90,11 @@ def main(argv=None) -> dict:
                          "aggregation); with a tree, each round's root "
                          "traffic is one O(params) pooled fold record "
                          "per surviving edge (runtime/agg_tree.py)")
+    ap.add_argument("--mesh", default="",
+                    help="pod,data,model: run on that device mesh, one "
+                         "cohort slice per pod (data and model 1), the "
+                         "round all-gathering the packed words over "
+                         "pod (e.g. 4,1,1 on a four-chip host)")
     ap.add_argument("--agg-fault-prob", type=float, default=0.0,
                     help="per-round edge-aggregator crash probability "
                          "(requires --tree-fanout); cohorts of a "
@@ -107,9 +112,16 @@ def main(argv=None) -> dict:
                               downlink_bits=args.downlink_bits,
                               seed=args.seed)
 
+    mesh = None
+    if args.mesh:
+        shape = tuple(int(n) for n in args.mesh.split(","))
+        if len(shape) != 3 or args.cohorts % shape[0]:
+            ap.error(f"--mesh {args.mesh}: give pod,data,model with pod "
+                     f"dividing --cohorts {args.cohorts}")
+        mesh = meshlib.make_debug_pod_mesh(*shape)
     plan = fedapi.get_launch_plan(args.algo)(
         api, scfg, key=key, cohorts=args.cohorts,
-        optimizer=args.score_opt, codec=args.codec)
+        optimizer=args.score_opt, codec=args.codec, mesh=mesh)
     state, step_fn, round_fn = plan.state, plan.step_fn, plan.round_fn
 
     # hierarchical aggregator tree (runtime/agg_tree.py): the barrier

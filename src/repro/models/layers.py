@@ -60,23 +60,31 @@ def masked_dense_apply(x: jax.Array, p) -> jax.Array:
     return x @ p
 
 
-def masked_grouped_apply(x: jax.Array, p) -> jax.Array:
-    """y[e] = x[e] @ w_eff[e] for a stacked (E, K, N) weight (MoE
-    expert einsums; x: (E, ..., K)).
+def masked_grouped_apply(x: jax.Array, p, layout: ops.GroupLayout
+                         ) -> jax.Array:
+    """y[r] = x[r] @ w_eff[e] for the group-contiguous rows r of group e
+    (`layout`, `ops.group_layout`) and a stacked (E, K, N) weight (MoE
+    experts).  Rows of tiles past the live ones are not computed.
 
-    Plain array: the batched einsum (float baselines, materialized
-    effective params).  MaskedLeaf: ONE grouped Pallas launch for all
-    E groups — per-group `seed`/`off` stream coordinates make each
-    expert's mask exactly its slice of the leaf's flat uplink stream,
-    and the stacked m⊙w never exists in HBM on either pass."""
+    Plain array: `jax.lax.ragged_dot` over the groups' tiles (float
+    baselines, materialized effective params).  MaskedLeaf: ONE grouped
+    Pallas launch for all E groups — per-group `seed`/`off` stream
+    coordinates make each expert's mask exactly its slice of the leaf's
+    flat uplink stream, and the stacked m⊙w never exists in HBM on
+    either pass."""
     if isinstance(p, MaskedLeaf):
         if p.mode == "threshold":
-            return ops.masked_dense_grouped_threshold(x, p.w, p.s, p.tau)
-        return ops.masked_dense_grouped(x, p.w, p.s, p.seed, p.off)
-    shape = x.shape
-    y = jnp.einsum("ecd,edf->ecf", x.reshape(shape[0], -1, shape[-1]),
-                   p)
-    return y.reshape(shape[:-1] + (p.shape[-1],))
+            return ops.masked_dense_grouped_threshold(x, p.w, p.s, layout,
+                                                      p.tau)
+        return ops.masked_dense_grouped(x, p.w, p.s, p.seed, p.off,
+                                        layout)
+    E = p.shape[0]
+    bm = x.shape[0] // layout.tile_group.shape[0]
+    live = jnp.arange(layout.tile_group.shape[0]) < layout.n_live[0]
+    rows = bm * jnp.sum(jax.nn.one_hot(layout.tile_group, E,
+                                       dtype=jnp.int32) * live[:, None],
+                        axis=0)
+    return jax.lax.ragged_dot(x, p.astype(x.dtype), rows)
 
 
 def masked_conv1d_apply(x: jax.Array, p) -> jax.Array:
@@ -191,12 +199,69 @@ def rope_freqs(head_dim, theta=10000.0, dtype=jnp.float32):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta=10000.0):
-    """x: (..., S, H, Hd), positions: broadcastable to (..., S)."""
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling as DeepSeek-V2 publishes it
+    (`DeepseekV2YarnRotaryEmbedding`; `rope_scaling` of its config)."""
+    factor: float
+    original_max_pos: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """0.1 * mscale * ln(scale) + 1 (1 for scale <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def _yarn_dim(rotations: float, dim: int, base: float, max_pos: int):
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(base)))
+
+
+def yarn_freqs(head_dim: int, theta: float, y: Yarn) -> jax.Array:
+    """Rotary frequencies mixed by a linear ramp between the
+    interpolated base^(-2i/d)/factor (low frequencies) and the original
+    base^(-2i/d) (high ones), over the correction range that beta_fast
+    and beta_slow give at the original context length."""
+    freq = rope_freqs(head_dim, theta)
+    low = max(math.floor(_yarn_dim(y.beta_fast, head_dim, theta,
+                                   y.original_max_pos)), 0)
+    high = min(math.ceil(_yarn_dim(y.beta_slow, head_dim, theta,
+                                   y.original_max_pos)), head_dim - 1)
+    high = high + 0.001 if low == high else high
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp          # 1: the original frequency
+    return freq / y.factor * (1.0 - keep) + freq * keep
+
+
+def yarn_attn_scale(qk_dim: int, y: Yarn | None) -> float:
+    """Softmax scale qk_dim^-0.5, times mscale(factor,
+    mscale_all_dim)^2 under YaRN."""
+    s = qk_dim ** -0.5
+    if y is not None and y.mscale_all_dim:
+        s *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return s
+
+
+def apply_rope(x, positions, theta=10000.0, yarn: Yarn | None = None):
+    """x: (..., S, H, Hd), positions: broadcastable to (..., S).  With
+    `yarn`, YaRN frequencies and cos/sin scaled by mscale(factor,
+    mscale) / mscale(factor, mscale_all_dim)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)
+    if yarn is None:
+        freqs, amp = rope_freqs(hd, theta), 1.0
+    else:
+        freqs = yarn_freqs(hd, theta, yarn)
+        amp = (yarn_mscale(yarn.factor, yarn.mscale)
+               / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, Hd/2)
     sin, cos = jnp.sin(ang)[..., None, :], jnp.cos(ang)[..., None, :]
+    if amp != 1.0:
+        sin, cos = sin * amp, cos * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)
@@ -258,19 +323,23 @@ def _attn_scores_mask(q_pos, k_pos, window: int | None, causal=True):
 
 @jax.named_scope("attention_core")
 def attention_core(q, k, v, q_pos, k_pos, window=None, causal=True,
-                   chunk_kv: int | None = None, soft_cap: float | None = None):
+                   chunk_kv: int | None = None, soft_cap: float | None = None,
+                   scale: float | None = None):
     """q: (B, Sq, H, Hd); k: (B, Sk, Kv, Hd); v: (B, Sk, Kv, Dv).
-    GQA by head repetition; Dv may differ from Hd (MLA).
+    GQA by head repetition; Dv may differ from Hd (MLA).  `scale`
+    defaults to Hd^-0.5.
 
     chunk_kv: if set, run online-softmax over KV chunks (flash-style
-    memory behaviour: never materializes the (Sq, Sk) matrix). This is
-    the memory path for 32k prefill / 500k contexts.
+    memory behaviour: never materializes the (Sq, Sk) matrix; each
+    chunk's scores are recomputed on the backward pass, so training
+    keeps only the running (max, sum, output) per chunk). This is the
+    memory path for 32k prefill / 500k contexts and 8k training.
     """
     B, Sq, H, Hd = q.shape
     Kv = k.shape[2]
     Dv = v.shape[-1]
     rep = H // Kv
-    scale = 1.0 / math.sqrt(Hd)
+    scale = 1.0 / math.sqrt(Hd) if scale is None else scale
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, Kv, rep, Hd)
 
     if chunk_kv is None:
@@ -312,7 +381,7 @@ def attention_core(q, k, v, q_pos, k_pos, window=None, causal=True,
     l0 = jnp.zeros((B, Kv, rep, Sq), jnp.float32)
     a0 = jnp.zeros((B, Kv, rep, Sq, Dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0),
+        jax.checkpoint(body), (m0, l0, a0),
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0), pc))
     o = acc / jnp.maximum(l, 1e-30)[..., None]
     o = jnp.moveaxis(o, -2, 1).reshape(B, Sq, H, Dv)
@@ -392,9 +461,13 @@ def mla_init(key, d_model, n_heads, kv_lora, q_lora, qk_nope, qk_rope,
 
 
 def mla_apply(p, x, positions, n_heads, kv_lora, qk_nope, qk_rope, v_head,
-              rope_theta=10000.0, chunk_kv=None, cache_kv=None):
+              rope_theta=10000.0, chunk_kv=None, cache_kv=None,
+              yarn: Yarn | None = None):
     """MLA forward. cache_kv: (c_kv, k_rope) prefilled tensors for decode
-    (the compressed-KV cache — MLA's memory saving)."""
+    (the compressed-KV cache — MLA's memory saving).  `yarn`: YaRN
+    rotary frequencies and amplitude on the decoupled rope dims, and the
+    softmax scale (qk_nope + qk_rope)^-0.5 * mscale(factor,
+    mscale_all_dim)^2, as DeepSeek-V2 publishes them."""
     B, S, D = x.shape
     if "w_dq" in p:
         cq = rms_norm({"scale": p["q_norm_scale"]},
@@ -405,12 +478,12 @@ def mla_apply(p, x, positions, n_heads, kv_lora, qk_nope, qk_rope, v_head,
         q = masked_dense_apply(x, p["w_q"]).reshape(
             B, S, n_heads, qk_nope + qk_rope)
     q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
-    q_rope = apply_rope(q_rope, positions, rope_theta)
+    q_rope = apply_rope(q_rope, positions, rope_theta, yarn)
 
     dkv = masked_dense_apply(x, p["w_dkv"])
     c_kv = rms_norm({"scale": p["kv_norm_scale"]}, dkv[..., :kv_lora])
     k_rope_new = apply_rope(dkv[..., kv_lora:][:, :, None, :], positions,
-                            rope_theta)  # (B,S,1,qk_rope)
+                            rope_theta, yarn)  # (B,S,1,qk_rope)
 
     if cache_kv is not None:
         c_kv_all, k_rope_all = cache_kv
@@ -430,8 +503,9 @@ def mla_apply(p, x, positions, n_heads, kv_lora, qk_nope, qk_rope, v_head,
             k_rope_all, k_nope.shape[:3] + (qk_rope,))], axis=-1)
     qfull = jnp.concatenate([q_nope, q_rope], axis=-1)
     o = attention_core(qfull, k, v, q_pos, k_pos, window=None, causal=True,
-                       chunk_kv=chunk_kv)
-    # o has head_dim v_head? attention_core keeps q's Hd; v dims differ.
+                       chunk_kv=chunk_kv,
+                       scale=yarn_attn_scale(qk_nope + qk_rope, yarn))
+    # o: (B, S, H, v_head) — attention_core takes Dv from v
     return masked_dense_apply(o.reshape(B, S, -1), p["w_o"]), \
         (c_kv, k_rope_new)
 
@@ -464,18 +538,23 @@ def mlp_apply(p, x, act="silu"):
 
 
 # ---------------------------------------------------------------------------
-# MoE (capacity-based dispatch, EP-shardable on the expert axis)
+# MoE (dropless sort/gather dispatch over the held experts)
 # ---------------------------------------------------------------------------
 
 
-def moe_init(key, d_model, moe_d_ff, n_experts, n_shared, dtype=DEFAULT_DTYPE):
+def moe_init(key, d_model, moe_d_ff, n_experts, n_shared, n_held=0,
+             dtype=DEFAULT_DTYPE):
+    """Router over all `n_experts`; weights of experts [0, n_held) only
+    (n_held 0: all of them).  A held expert keeps its index, so its
+    stacked block sits at mask-stream offset e*K*N of the leaf."""
+    n_held = n_held or n_experts
     ks = jax.random.split(key, 5)
     p = {
         "router_w": dense_init(ks[0], (d_model, n_experts), jnp.float32),
-        # stacked expert weights: (E, ...) — EP shards axis 0
-        "w_up": dense_init(ks[1], (n_experts, d_model, moe_d_ff), dtype),
-        "w_gate": dense_init(ks[2], (n_experts, d_model, moe_d_ff), dtype),
-        "w_down": dense_init(ks[3], (n_experts, moe_d_ff, d_model), dtype,
+        # stacked expert weights: (E_held, ...) — EP shards axis 0
+        "w_up": dense_init(ks[1], (n_held, d_model, moe_d_ff), dtype),
+        "w_gate": dense_init(ks[2], (n_held, d_model, moe_d_ff), dtype),
+        "w_down": dense_init(ks[3], (n_held, moe_d_ff, d_model), dtype,
                              fan_in=moe_d_ff),
     }
     if n_shared:
@@ -483,82 +562,92 @@ def moe_init(key, d_model, moe_d_ff, n_experts, n_shared, dtype=DEFAULT_DTYPE):
     return p
 
 
-def moe_apply(p, x, n_experts, top_k, capacity_factor=1.25,
-              router_noise=0.0, key=None, block_dispatch=0):
-    """GShard-style capacity dispatch. x: (B, S, D) -> (B, S, D).
+def moe_route(probs: jax.Array, top_k: int, n_held: int):
+    """Greedy top-k over the router's probabilities: (gates, experts,
+    held).  Gates are the top-k probabilities as they are (not
+    renormalized); a (token, slot) pair is computed here when its
+    expert is held, and no held pair is ever dropped."""
+    gval, gidx = jax.lax.top_k(probs, top_k)
+    return gval, gidx, gidx < n_held
 
-    Dispatch/combine are einsums so GSPMD shards them (tokens on data,
-    experts on model). Dropped tokens (over capacity) fall through on the
-    residual path (plus shared experts for DeepSeek-V2).
 
-    block_dispatch=G > 0: tokens are split into G blocks, each with its
-    own (G x smaller) expert capacity, and dispatched independently.
-    The (T, E, C) dispatch tensor shrinks Gx — the one-hot dispatch
-    einsums cost O(T * E * C * D) = O(T^2 * top_k * cf * D / G), so
-    block-local dispatch cuts the dominant non-useful FLOPs by G while
-    matching real per-device capacity semantics (docs/DESIGN.md §7).
+def _expert_dim(p):
+    w = p["w_up"]
+    return (w.w if isinstance(w, MaskedLeaf) else w).shape
+
+
+def moe_dispatch(gidx, held, n_held: int, plan: ops.GroupedPlan):
+    """Sort the held (token, slot) pairs by expert into a group-
+    contiguous buffer of `plan.tiles` row tiles.  Returns the layout
+    and, per buffer row, its pair (T*k for rows that hold none)."""
+    T, k = gidx.shape
+    P = T * k
+    key = jnp.where(held, gidx, n_held).reshape(P).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(jax.nn.one_hot(key, n_held, dtype=jnp.int32), axis=0)
+    layout = ops.group_layout(sizes, plan.bm, plan.tiles)
+    first = jnp.cumsum(sizes) - sizes          # sorted index of each
+    rows = jnp.arange(plan.tiles * plan.bm, dtype=jnp.int32)
+    grp = jnp.repeat(layout.tile_group, plan.bm)
+    rank = rows - layout.starts[grp]
+    live = (rank < sizes[grp]) & (rows < layout.n_live[0] * plan.bm)
+    pair = jnp.take(order, jnp.clip(first[grp] + rank, 0, P - 1))
+    return layout, jnp.where(live, pair, P)
+
+
+def moe_apply(p, x, n_experts, top_k, router_noise=0.0, key=None):
+    """Dropless MoE over the experts this chip holds. x: (B, S, D).
+
+    The router keeps all `n_experts` outputs (float32 softmax, greedy
+    top-k, gates not renormalized).  The (token, slot) pairs routed to
+    held experts [0, E_held) are sorted by expert and their rows
+    gathered into a group-contiguous buffer (static bound
+    T*min(top_k, E_held) rows plus per-group padding to the grouped
+    kernels' row block, `ops.grouped_plan`); the experts run as grouped
+    kernels over it, and a gate-weighted scatter-add brings the rows
+    back to their tokens.  Pairs routed to experts not held here
+    contribute nothing; no pair is dropped, whatever the imbalance.
+    Shared experts run on every token.  Returns (y, aux), aux the
+    Switch-style balance loss over all `n_experts`.
     """
     B, S, D = x.shape
-    if block_dispatch and B * S % block_dispatch == 0 \
-            and B * S // block_dispatch >= 8:
-        G = block_dispatch
-        xt = x.reshape(G, (B * S) // G, 1, D)
-        y, aux = jax.vmap(
-            lambda xb: moe_apply(p, xb, n_experts, top_k,
-                                 capacity_factor, 0.0, None, 0))(xt)
-        return y.reshape(B, S, D), jnp.mean(aux)
     T = B * S
     xt = x.reshape(T, D)
-    logits = (xt.astype(jnp.float32) @ p["router_w"])
-    if router_noise > 0 and key is not None:
-        logits = logits + router_noise * jax.random.normal(
-            key, logits.shape)
-    probs = jax.nn.softmax(logits, axis=-1)                 # (T, E)
-    gval, gidx = jax.lax.top_k(probs, top_k)                # (T, k)
-    gval = gval / jnp.maximum(jnp.sum(gval, -1, keepdims=True), 1e-9)
-
-    cap = max(int(T * top_k * capacity_factor / n_experts), 4)
-    # position of each (token, slot) within its expert queue
-    onehot = jax.nn.one_hot(gidx, n_experts, dtype=jnp.float32)  # (T,k,E)
-    flat = onehot.reshape(T * top_k, n_experts)
-    pos_in_e = (jnp.cumsum(flat, axis=0) - flat).reshape(
-        T, top_k, n_experts)
-    pos = jnp.sum(pos_in_e * onehot, axis=-1)               # (T, k)
-    keep = pos < cap
-    gval = gval * keep
-
-    # dispatch tensor (T, E, C)
-    pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32) \
-        * keep[..., None]                                    # (T,k,C)
-    disp = jnp.einsum("tke,tkc->tec", onehot, pos_oh)        # (T,E,C)
-    xe = jnp.einsum("tec,td->ecd", disp, xt.astype(jnp.float32))
-    # xe stays f32 through the expert stack: the chain then carries NO
-    # intermediate bf16 rounding, so the fused (Pallas) and plain
-    # (einsum) branches of masked_grouped_apply are bit-identical —
-    # XLA's excess-precision pass would elide a bf16 round-trip on the
-    # einsum branch but not on a physical pallas output buffer
-
-    # stacked (E, ., .) expert weights ride the GROUPED fused kernels:
-    # one pallas_call per projection covers all E experts (per-expert
-    # seed/off = expert's slice of the leaf's hash stream), so the
-    # stacked m⊙w is never materialized — plain arrays (float
-    # baselines, REPRO_EFF_PATH) take the batched einsum
-    h = jax.nn.silu(masked_grouped_apply(xe, p["w_gate"])) \
-        * masked_grouped_apply(xe, p["w_up"])
-    ye = masked_grouped_apply(h, p["w_down"])                # (E,C,D)
-
-    comb = jnp.einsum("tke,tkc,tk->tec", onehot, pos_oh,
-                      gval.astype(jnp.float32))
-    y = jnp.einsum("tec,ecd->td", comb, ye.astype(jnp.float32))
-    y = y.astype(x.dtype).reshape(B, S, D)
-
+    E_h, _, F = _expert_dim(p)
+    with jax.named_scope("moe_router"):
+        logits = xt.astype(jnp.float32) @ p["router_w"]
+        if router_noise > 0 and key is not None:
+            logits = logits + router_noise * jax.random.normal(
+                key, logits.shape)
+        probs = jax.nn.softmax(logits, axis=-1)             # (T, E)
+        gval, gidx, held = moe_route(probs, top_k, E_h)
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.mean(jnp.sum(jax.nn.one_hot(gidx, n_experts,
+                                             dtype=jnp.float32), 1), 0)
+        aux = n_experts * jnp.sum(me * ce)
+    with jax.named_scope("moe_dispatch"):
+        plan = ops.grouped_plan(T * min(top_k, E_h), D, F, E_h,
+                                T * top_k / n_experts)
+        layout, pair = moe_dispatch(gidx, held, E_h, plan)
+        tok = pair // top_k                   # T for rows holding none
+        xe = jnp.take(xt, tok, axis=0, mode="fill", fill_value=0)
+        gate = jnp.take(gval.reshape(-1), pair, mode="fill",
+                        fill_value=0)
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(masked_grouped_apply(xe, p["w_gate"], layout)) \
+            * masked_grouped_apply(xe, p["w_up"], layout)
+        ye = masked_grouped_apply(h, p["w_down"], layout)
+    with jax.named_scope("moe_combine"):
+        # rows holding no pair (padding, dead tiles: never written by
+        # the kernels) are selected away, not multiplied by a zero gate
+        contrib = jnp.where((pair < T * top_k)[:, None],
+                            gate[:, None] * ye.astype(jnp.float32), 0.0)
+        y = jnp.zeros((T, D), jnp.float32).at[tok].add(contrib,
+                                                        mode="drop")
+        y = y.astype(x.dtype).reshape(B, S, D)
     if "shared" in p:
-        y = y + mlp_apply(p["shared"], x)
-
-    # aux load-balancing loss (Switch-style)
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(onehot.sum(1), axis=0)
-    aux = n_experts * jnp.sum(me * ce)
+        with jax.named_scope("moe_shared"):
+            y = y + mlp_apply(p["shared"], x)
     return y, aux
 
 

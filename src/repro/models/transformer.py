@@ -40,7 +40,8 @@ def _layer_init(key, cfg: ArchConfig, moe: bool):
                                cfg.n_kv_heads, cfg.hd, cfg.qkv_bias)
     if moe:
         p["moe"] = L.moe_init(ks[1], cfg.d_model, cfg.moe_d_ff,
-                              cfg.n_experts, cfg.n_shared_experts)
+                              cfg.n_experts, cfg.n_shared_experts,
+                              cfg.n_held)
     else:
         p["mlp"] = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff)
     return p
@@ -95,6 +96,14 @@ def layer_windows(cfg: ArchConfig, n: int):
 # ---------------------------------------------------------------------------
 
 
+def yarn_of(cfg: ArchConfig) -> Optional[L.Yarn]:
+    if not cfg.yarn_factor:
+        return None
+    return L.Yarn(cfg.yarn_factor, cfg.yarn_original_max_pos,
+                  cfg.yarn_beta_fast, cfg.yarn_beta_slow, cfg.yarn_mscale,
+                  cfg.yarn_mscale_all_dim)
+
+
 def _block(cfg: ArchConfig, moe: bool, x, lp, positions, window, theta,
            chunk_kv, mrope_positions):
     with jax.named_scope("attention"):
@@ -103,7 +112,8 @@ def _block(cfg: ArchConfig, moe: bool, x, lp, positions, window, theta,
             attn_out, kv = L.mla_apply(
                 lp["attn"], h, positions, cfg.n_heads, cfg.kv_lora_rank,
                 cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
-                rope_theta=cfg.rope_theta, chunk_kv=chunk_kv)
+                rope_theta=cfg.rope_theta, chunk_kv=chunk_kv,
+                yarn=yarn_of(cfg))
         else:
             attn_out, kv = L.gqa_apply(
                 lp["attn"], h, positions, cfg.n_heads, cfg.n_kv_heads,
@@ -114,9 +124,7 @@ def _block(cfg: ArchConfig, moe: bool, x, lp, positions, window, theta,
         x = x + attn_out
     if moe:
         h = L.rms_norm(lp["ffn_norm"], x)
-        ffn_out, aux = L.moe_apply(lp["moe"], h, cfg.n_experts, cfg.top_k,
-                                   cfg.capacity_factor,
-                                   block_dispatch=cfg.moe_block_dispatch)
+        ffn_out, aux = L.moe_apply(lp["moe"], h, cfg.n_experts, cfg.top_k)
     else:
         with jax.named_scope("mlp"):
             h = L.rms_norm(lp["ffn_norm"], x)
@@ -155,6 +163,8 @@ def forward(params: Pytree, cfg: ArchConfig, tokens: jax.Array,
         # positions for masking still linear
     B, S, _ = x.shape
     positions = jnp.arange(S)
+    if chunk_kv is None and cfg.attn_chunk and S > cfg.attn_chunk:
+        chunk_kv = cfg.attn_chunk
 
     aux_total = jnp.float32(0.0)
     caches = {}
@@ -441,7 +451,7 @@ def decode_step(params: Pytree, cfg: ArchConfig, cache: Pytree,
                               dkv[..., :cfg.kv_lora_rank])
         k_rope_new = L.apply_rope(
             dkv[..., cfg.kv_lora_rank:][:, :, None, :], positions,
-            cfg.rope_theta)
+            cfg.rope_theta, yarn_of(cfg))
         ckv = jax.lax.dynamic_update_slice(
             lc["c_kv"], c_kv_new.astype(lc["c_kv"].dtype), (0, pos, 0))
         krp = jax.lax.dynamic_update_slice(
@@ -450,7 +460,8 @@ def decode_step(params: Pytree, cfg: ArchConfig, cache: Pytree,
         out, _ = L.mla_apply(
             lp["attn"], h, positions, cfg.n_heads, cfg.kv_lora_rank,
             cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
-            rope_theta=cfg.rope_theta, cache_kv=(ckv, krp))
+            rope_theta=cfg.rope_theta, cache_kv=(ckv, krp),
+            yarn=yarn_of(cfg))
         return out, {"c_kv": ckv, "k_rope": krp}
 
     def run_stack(x, stacked, cache_part, moe, offset):
@@ -469,7 +480,7 @@ def decode_step(params: Pytree, cfg: ArchConfig, cache: Pytree,
             h = L.rms_norm(lp["ffn_norm"], x)
             if moe:
                 ffn_out, _ = L.moe_apply(lp["moe"], h, cfg.n_experts,
-                                         cfg.top_k, cfg.capacity_factor)
+                                         cfg.top_k)
             else:
                 ffn_out = L.mlp_apply(lp["mlp"], h, cfg.act)
             return x + ffn_out, nc

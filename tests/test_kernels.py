@@ -453,21 +453,25 @@ def _grouped_operands(E, M, K, N, seed=7, dtype=jnp.float32):
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_masked_matmul_grouped_allclose(shape, dtype):
     E, M, K, N = shape
-    x, w, s, g, seeds, offs = _grouped_operands(E, M, K, N, dtype=dtype)
-    y = masked_matmul_grouped(x, w, s, seeds, offs, interpret=True)
-    y_ref = ref.masked_matmul_grouped(x, w, s, seeds, offs)
+    x3, w, s, g3, seeds, offs = _grouped_operands(E, M, K, N, dtype=dtype)
+    x, tg, nl = ref.uniform_groups(x3)
+    g, _, _ = ref.uniform_groups(g3)
+    y = masked_matmul_grouped(x, w, s, seeds, offs, tg, nl,
+                              interpret=True)
+    y_ref = ref.masked_matmul_grouped(x, w, s, seeds, offs, tg, nl)
     np.testing.assert_allclose(
         np.asarray(y, np.float32), np.asarray(y_ref, np.float32),
         rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5,
         atol=1e-2 if dtype == jnp.bfloat16 else 1e-4)
-    dx = masked_matmul_grouped_dx(g, w, s, seeds, offs, interpret=True)
-    dx_ref = ref.masked_matmul_grouped_dx(g, w, s, seeds, offs)
+    dx = masked_matmul_grouped_dx(g, w, s, seeds, offs, tg, nl,
+                                  interpret=True)
+    dx_ref = ref.masked_matmul_grouped_dx(g, w, s, seeds, offs, tg, nl)
     np.testing.assert_allclose(
         np.asarray(dx, np.float32), np.asarray(dx_ref, np.float32),
         rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5,
         atol=1e-2 if dtype == jnp.bfloat16 else 1e-4)
-    ds = masked_matmul_grouped_ds(x, g, w, s, interpret=True)
-    ds_ref = ref.masked_matmul_grouped_ds(x, g, w, s)
+    ds = masked_matmul_grouped_ds(x, g, w, s, tg, nl, interpret=True)
+    ds_ref = ref.masked_matmul_grouped_ds(x, g, w, s, tg, nl)
     np.testing.assert_allclose(np.asarray(ds), np.asarray(ds_ref),
                                rtol=1e-3, atol=1e-3)
 
@@ -481,16 +485,28 @@ def test_grouped_masks_bit_identical_across_tilings(blocks):
     E, K, N = 3, 256, 256
     _, _, s, _, seeds, offs = _grouped_operands(E, K, K, N)
     w1 = jnp.ones((E, K, N), jnp.float32)
-    eye = jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K))
-    m_fwd = masked_matmul_grouped(eye, w1, s, seeds, offs, bm=128,
-                                  bn=bn, bk=bk, interpret=True)
-    eyeN = jnp.broadcast_to(jnp.eye(N, dtype=jnp.float32), (E, N, N))
-    m_dx = masked_matmul_grouped_dx(eyeN, w1, s, seeds, offs, bm=128,
-                                    bn=bn, bk=bk, interpret=True)
+    eye, tg, nl = ref.uniform_groups(
+        jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K)))
+    m_fwd = ref.ungroup(masked_matmul_grouped(
+        eye, w1, s, seeds, offs, tg, nl, bm=128, bn=bn, bk=bk,
+        interpret=True), E, K)
+    eyeN, tgN, nlN = ref.uniform_groups(
+        jnp.broadcast_to(jnp.eye(N, dtype=jnp.float32), (E, N, N)))
+    m_dx = ref.ungroup(masked_matmul_grouped_dx(
+        eyeN, w1, s, seeds, offs, tgN, nlN, bm=128, bn=bn, bk=bk,
+        interpret=True), E, N)
     for e in range(E):
         m_ref = ref.sample_mask(s[e], 7, e * K * N).astype(np.float32)
         assert np.array_equal(np.asarray(m_fwd[e]), m_ref), (e, blocks)
         assert np.array_equal(np.asarray(m_dx[e]).T, m_ref), (e, blocks)
+
+
+def _uniform_layout(x3, bm=128):
+    rows, tg, nl = ref.uniform_groups(x3, bm)
+    E = x3.shape[0]
+    t = tg.shape[0] // E
+    return rows, ops.GroupLayout(
+        tg, nl, jnp.arange(E, dtype=jnp.int32) * t * bm)
 
 
 def test_grouped_offsets_equal_uplink_stream():
@@ -502,9 +518,10 @@ def test_grouped_offsets_equal_uplink_stream():
     words = ref.sample_and_pack(ss.reshape(1, -1),
                                 jnp.asarray([31], jnp.uint32))
     flat = ref.unpack_bits(words[0], E * K * N).reshape(E, K, N)
-    eye = jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K))
-    m = ops.masked_dense_grouped(eye, jnp.ones((E, K, N), jnp.float32),
-                                 ss, 31)
+    eye, lay = _uniform_layout(
+        jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K)))
+    m = ref.ungroup(ops.masked_dense_grouped(
+        eye, jnp.ones((E, K, N), jnp.float32), ss, 31, None, lay), E, K)
     assert np.array_equal(np.asarray(m), np.asarray(flat, np.float32))
 
 
@@ -514,15 +531,18 @@ def test_masked_dense_grouped_grads_match_ref(shape):
     grouped STE backward — including MXU-unaligned shapes via
     padding."""
     E, M, K, N = shape
-    x, w, s, g, seeds, offs = _grouped_operands(E, M, K, N, seed=13)
+    x3, w, s, g, seeds, offs = _grouped_operands(E, M, K, N, seed=13)
+    x, lay = _uniform_layout(x3)
 
     def loss(x, s):
-        return jnp.sum(ops.masked_dense_grouped(x, w, s, 13, offs) ** 2)
+        return jnp.sum(ops.masked_dense_grouped(x, w, s, 13, offs, lay)
+                       ** 2)
 
     gx, gs = jax.grad(loss, argnums=(0, 1))(x, s)
-    y_ref = ref.masked_matmul_grouped(x, w, s, seeds, offs)
+    tg, nl = lay.tile_group, lay.n_live
+    y_ref = ref.masked_matmul_grouped(x, w, s, seeds, offs, tg, nl)
     dx_ref, ds_ref = ref.masked_dense_grouped_bwd(x, w, s, seeds, offs,
-                                                  2.0 * y_ref)
+                                                  2.0 * y_ref, tg, nl)
     np.testing.assert_allclose(np.asarray(gx), np.asarray(dx_ref),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(gs), np.asarray(ds_ref),
@@ -533,18 +553,20 @@ def test_masked_dense_grouped_threshold_matches_eff():
     """Grouped FedMask mode: threshold masks through the grouped
     kernel equal the materialized threshold reference."""
     E, M, K, N = 2, 12, 40, 24
-    x, w, s, _, _, _ = _grouped_operands(E, M, K, N)
+    x3, w, s, _, _, _ = _grouped_operands(E, M, K, N)
+    x, lay = _uniform_layout(x3)
     tau = 0.4
-    y = ops.masked_dense_grouped_threshold(x, w, s, tau)
+    y = ref.ungroup(ops.masked_dense_grouped_threshold(x, w, s, lay, tau),
+                    E, M)
     eff = jax.vmap(lambda se, we: ref.threshold_mask(se, tau).astype(
         jnp.float32) * we)(s, w)
-    y_ref = jnp.einsum("emk,ekn->emn", x, eff)
+    y_ref = jnp.einsum("emk,ekn->emn", x3, eff)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
 
     def loss(s):
-        return jnp.sum(ops.masked_dense_grouped_threshold(x, w, s, tau)
-                       ** 2)
+        return jnp.sum(ops.masked_dense_grouped_threshold(x, w, s, lay,
+                                                          tau) ** 2)
 
     gs = jax.grad(loss)(s)
     assert gs.shape == s.shape and bool(jnp.all(jnp.isfinite(gs)))

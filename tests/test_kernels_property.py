@@ -107,11 +107,14 @@ def test_grouped_masks_bit_identical_across_tilings_property(
     seeds = jnp.full((E,), seed, jnp.uint32)
     offs = jnp.arange(E, dtype=jnp.uint32) * jnp.uint32(K * N)
     w1 = jnp.ones((E, K, N), jnp.float32)
-    eye = jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K))
-    m_fwd = masked_matmul_grouped(eye, w1, s, seeds, offs, bm=128,
-                                  bn=bn, bk=bk, interpret=True)
-    m_dx = masked_matmul_grouped_dx(eye, w1, s, seeds, offs, bm=128,
-                                    bn=bn, bk=bk, interpret=True)
+    eye, tg, nl = ref.uniform_groups(
+        jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K)))
+    m_fwd = ref.ungroup(masked_matmul_grouped(
+        eye, w1, s, seeds, offs, tg, nl, bm=128, bn=bn, bk=bk,
+        interpret=True), E, K)
+    m_dx = ref.ungroup(masked_matmul_grouped_dx(
+        eye, w1, s, seeds, offs, tg, nl, bm=128, bn=bn, bk=bk,
+        interpret=True), E, K)
     for e in range(E):
         m_ref = ref.sample_mask(s[e], seed, e * K * N).astype(
             np.float32)
@@ -132,9 +135,12 @@ def test_grouped_offsets_equal_uplink_stream_property(seed, E, kn):
     words = ref.sample_and_pack(s.reshape(1, -1),
                                 jnp.asarray([seed], jnp.uint32))
     flat = ref.unpack_bits(words[0], E * K * N).reshape(E, K, N)
-    eye = jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K))
-    m = ops.masked_dense_grouped(eye, jnp.ones((E, K, N), jnp.float32),
-                                 s, seed)
+    eye, tg, nl = ref.uniform_groups(
+        jnp.broadcast_to(jnp.eye(K, dtype=jnp.float32), (E, K, K)))
+    lay = ops.GroupLayout(tg, nl, jnp.arange(E, dtype=jnp.int32)
+                          * (tg.shape[0] // E) * 128)
+    m = ref.ungroup(ops.masked_dense_grouped(
+        eye, jnp.ones((E, K, N), jnp.float32), s, seed, None, lay), E, K)
     assert np.array_equal(np.asarray(m), np.asarray(flat, np.float32))
 
 

@@ -62,33 +62,6 @@ def test_remat_preserves_forward_and_grads():
                                    rtol=2e-2, atol=1e-3)
 
 
-def test_moe_block_dispatch_matches_global_when_capacity_ample():
-    key = jax.random.PRNGKey(1)
-    D, E, F = 32, 8, 16
-    p = L.moe_init(key, D, F, E, n_shared=0)
-    x = jax.random.normal(key, (4, 64, D), jnp.float32)
-    y0, _ = L.moe_apply(p, x, E, 2, capacity_factor=8.0)
-    yb, _ = L.moe_apply(p, x, E, 2, capacity_factor=8.0,
-                        block_dispatch=8)
-    np.testing.assert_allclose(np.asarray(y0), np.asarray(yb),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_moe_block_dispatch_smoke_grad():
-    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b",
-                                         smoke=True),
-                              moe_block_dispatch=4)
-    api = build_model(cfg)
-    key = jax.random.PRNGKey(2)
-    params = api.init_params(key)
-    batch = {"tokens": jax.random.randint(key, (2, 16), 0, cfg.vocab)}
-    loss, g = jax.value_and_grad(
-        lambda p: api.loss(api.forward(p, batch), batch))(params)
-    assert np.isfinite(float(loss))
-    assert all(bool(jnp.all(jnp.isfinite(x)))
-               for x in jax.tree_util.tree_leaves(g))
-
-
 def test_chunked_attention_matches_dense():
     key = jax.random.PRNGKey(3)
     B, S, H, Kv, Hd = 2, 64, 4, 2, 16

@@ -98,3 +98,36 @@ def test_kernels_count_toward_the_block_that_calls_them(op_names):
     bwd = {SR.scope_of(n, VOCAB["train"]) for n in kern
            if "transpose(" in n}
     assert bwd == {"attention", "mlp"}, bwd
+
+
+MOE_SCOPES = {"moe_router", "moe_dispatch", "moe_experts", "moe_shared",
+              "moe_combine"}
+
+
+@pytest.fixture(scope="module")
+def moe_op_names():
+    api = build_model(get_config("deepseek-v2-lite-16b", smoke=True))
+    scfg = steplib.StepConfig(lr=0.3, downlink_bits=8)
+    state = jax.eval_shape(lambda k: steplib.init_fed_state(
+        k, api, masking.MaskSpec(), C=1), jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 2, 16), jnp.int32)}
+    text = SR.fresh_text(jax.jit(steplib.make_train_step(api, scfg),
+                                 donate_argnums=0), state, batch)
+    return [n for names in re.findall(r'op_name="([^"]*)"', text)
+            for n in names.split(";")]
+
+
+def test_moe_block_names_its_phases(moe_op_names):
+    """A compiled MoE train step (MLA, routed and shared experts) holds
+    every MoE scope, MLA stays under `attention`, and the program
+    carries no scope outside the train vocabulary and the MoE one."""
+    vocab = VOCAB["train"] | MOE_SCOPES
+    found = {SR.scope_of(n, vocab) for n in moe_op_names}
+    assert MOE_SCOPES | {"attention", "attention_core", "mlp",
+                         "embed_head", "optimizer"} <= found, found
+    names = {p for n in moe_op_names for p in own_names(n)}
+    assert names <= vocab, names - vocab
+    grouped = [n for n in moe_op_names
+               if re.search(r"jit\(masked_matmul_grouped(_dx|_ds)?\)", n)]
+    assert grouped
+    assert {SR.scope_of(n, vocab) for n in grouped} == {"moe_experts"}

@@ -2,6 +2,7 @@
 train/round/serve steps must run end-to-end on CPU with real values."""
 import dataclasses
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -387,3 +388,59 @@ def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
             assert jax.config.jax_compilation_cache_dir == got
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+_POD_PLAN = r"""
+import os
+import pathlib
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import jax, jax.numpy as jnp, numpy as np
+from repro import api as fedapi
+from repro.configs import get_config
+from repro.launch import plans, steps as steplib
+from repro.launch import mesh as meshlib
+from repro.models import build_model
+
+api = build_model(get_config("internlm2-1.8b", smoke=True))
+scfg = steplib.StepConfig(lr=0.3, downlink_bits=8, seed=17)
+key = jax.random.PRNGKey(4)
+plan = fedapi.get_launch_plan("fedpm_reg")
+one = plan(api, scfg, key=key, cohorts=4, codec="arithmetic")
+pod = plan(api, scfg, key=key, cohorts=4, codec="arithmetic",
+           mesh=meshlib.make_debug_pod_mesh(4, 1, 1))
+toks = jax.random.randint(key, (4096,), 0, api.cfg.vocab)
+batch = one.make_batch(key, toks, 2, 16)
+outs = []
+for p in (one, pod):
+    st, m = p.step_fn(p.state, batch)
+    st, rm = p.round_fn(st)
+    outs.append((st, float(m["loss"]), float(rm["bits_measured"])))
+(a, la, ba), (b, lb, bb) = outs
+assert pod.state is not None and pod.shardings is not None
+assert abs(la - lb) < 1e-5 * abs(la), (la, lb)
+assert ba == bb, (ba, bb)
+for x, y in zip(jax.tree_util.tree_leaves(a["scores"]),
+                jax.tree_util.tree_leaves(b["scores"])):
+    np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
+print("POD_OK", la, ba)
+"""
+
+
+def test_pod_mesh_plan_matches_one_device_plan():
+    """The mask plan on a (4, 1, 1) pod mesh (state placed by
+    fed_state_shardings, train step and round under shard_map, one
+    cohort per device) trains and rounds four cohorts as the one-device
+    plan does: the same loss, the same measured uplink bits, the same
+    new scores — each cohort samples its masks from the stream it
+    samples without the mesh."""
+    import subprocess
+    import sys
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = {"PYTHONPATH": src, "PATH": "/usr/bin:/bin:/usr/local/bin",
+           "HOME": os.environ.get("HOME", "/tmp")}
+    out = subprocess.run([sys.executable, "-c", _POD_PLAN],
+                         capture_output=True, text=True, timeout=600,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "POD_OK" in out.stdout

@@ -31,7 +31,7 @@ from benchmarks.chip import trace_reduce as TR  # noqa: E402
 
 M = 1024                       # batch 2 x seq 512
 D, F, V = 2048, 8192, 92544    # internlm2-1.8b widths
-E, DE, FE = 64, 2048, 1408     # deepseek-v2-lite routed experts
+EH, DE, FE = 8, 2048, 1408     # deepseek-v2-lite experts held, widths
 HBM_BYTES = 16 * 2**30         # one v5e
 
 
@@ -71,15 +71,17 @@ def _dense_blocks(K, N, kernel):
 
 
 def _grouped_blocks(K, N):
-    """The padding and blocks `ops.masked_dense_grouped` launches with."""
+    """The padding and blocks `ops.masked_dense_grouped` launches with
+    at the deepseek-v2-lite share cell: seq 8192, top-6 of 64, 8 of
+    the experts held."""
+    plan = ops.grouped_plan(8192 * 6, K, N, 8, 8192 * 6 / 64)
     Kp, Np = ops._round_up(K, 128), ops._round_up(N, 128)
-    return Kp, Np, dict(bm=128, bn=ops._block_for(Np),
-                        bk=ops._block_for(Kp), n_logical=N,
-                        interpret=False)
+    return Kp, Np, plan, dict(bm=plan.bm, bn=plan.bn, bk=plan.bk,
+                              n_logical=N, interpret=False)
 
 
 _LEAVES = {"up": (D, F), "down": (F, D), "head": (D, V)}
-bf, f32, u32 = jnp.bfloat16, jnp.float32, jnp.uint32
+bf, f32, u32, i32 = jnp.bfloat16, jnp.float32, jnp.uint32, jnp.int32
 
 
 @pytest.mark.parametrize("leaf", sorted(_LEAVES))
@@ -123,24 +125,34 @@ def test_bitpack_compiles(one_chip, kernel):
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "dx", "ds"])
-def test_grouped_compiles(one_chip, kernel):
-    """One deepseek-v2-lite expert projection: E x (2048 -> 1408)."""
-    Kp, Np, kw = _grouped_blocks(DE, FE)
-    Me = 128                                   # tokens per expert
-    w = [((E, Kp, Np), bf), ((E, Kp, Np), f32)]
+@pytest.mark.parametrize("proj", ["up", "down"])
+def test_grouped_compiles(one_chip, kernel, proj):
+    """One deepseek-v2-lite expert projection of the share cell, 8 held
+    experts x (2048 -> 1408) or (1408 -> 2048), over the routed rows'
+    static buffer."""
+    K, N = (DE, FE) if proj == "up" else (FE, DE)
+    Kp, Np, plan, kw = _grouped_blocks(K, N)
+    R = plan.tiles * plan.bm
+    w = [((EH, Kp, Np), bf), ((EH, Kp, Np), f32)]
+    lay = [((plan.tiles,), i32), ((1,), i32)]
     if kernel == "fwd":
-        c = _compile(lambda x, w, s, sd, o: _mm.masked_matmul_grouped(
-            x, w, s, sd, o, **kw), one_chip, ((E, Me, Kp), bf), *w,
-            ((E,), u32), ((E,), u32))
+        c = _compile(lambda x, w, s, sd, o, tg, nl:
+                     _mm.masked_matmul_grouped(x, w, s, sd, o, tg, nl,
+                                               **kw),
+                     one_chip, ((R, Kp), bf), *w, ((EH,), u32),
+                     ((EH,), u32), *lay)
     elif kernel == "dx":
-        c = _compile(lambda g, w, s, sd, o: _mm.masked_matmul_grouped_dx(
-            g, w, s, sd, o, **kw), one_chip, ((E, Me, Np), bf), *w,
-            ((E,), u32), ((E,), u32))
+        c = _compile(lambda g, w, s, sd, o, tg, nl:
+                     _mm.masked_matmul_grouped_dx(g, w, s, sd, o, tg, nl,
+                                                  **kw),
+                     one_chip, ((R, Np), bf), *w, ((EH,), u32),
+                     ((EH,), u32), *lay)
     else:
         del kw["n_logical"]
-        c = _compile(lambda x, g, w, s: _mm.masked_matmul_grouped_ds(
-            x, g, w, s, **kw), one_chip, ((E, Me, Kp), bf),
-            ((E, Me, Np), bf), *w)
+        c = _compile(lambda x, g, w, s, tg, nl:
+                     _mm.masked_matmul_grouped_ds(x, g, w, s, tg, nl,
+                                                  **kw),
+                     one_chip, ((R, Kp), bf), ((R, Np), bf), *w, *lay)
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -186,6 +198,47 @@ def test_step_compiles_and_fits(one_chip, monkeypatch, step):
     for k in kernels:
         assert found.kernels((k,)) == [k], (k, calls)
     assert not calls & set(SR.TRAIN_SCOPES + SR.ROUND_SCOPES), calls
+    ma = c.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert live < HBM_BYTES, live
+
+
+def test_moe_share_train_step_compiles_and_fits(one_chip, monkeypatch):
+    """The whole jitted train step of the deepseek-v2-lite-16b share
+    cell (5 layers, 8 of 64 experts held, vocabulary 12800, 1 cohort x
+    seq 8192, state donated; the cell's own program settings) compiles
+    for one v5e, holds the dense and grouped kernels under their fixed
+    names, and fits the chip's 16 GiB."""
+    import dataclasses
+    from benchmarks.chip import harness as H
+    from benchmarks.chip.reference import moe as RM
+    from repro.configs import get_config
+    from repro.core import masking
+    from repro.launch import steps as steplib
+    from repro.models import build_model
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    conf = H.load_json(H.HERE / "configs" / "deepseek-v2-lite-16b.json")
+    arch = dataclasses.replace(get_config(conf["program_arch"]),
+                               **RM.program_arch(conf))
+    api = build_model(arch)
+    scfg = steplib.StepConfig(lr=0.3, downlink_bits=8)
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda k: steplib.init_fed_state(
+            k, api, masking.MaskSpec(), C=1), jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 1, 8192), jnp.int32,
+                                            sharding=one_chip)}
+    c = jax.jit(steplib.make_train_step(api, scfg),
+                donate_argnums=0).lower(state, batch).compile()
+    text = c.as_text()
+    calls = {TR.base_name(n) for n in re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+    assert calls == {"masked_matmul", "masked_matmul_dx",
+                     "masked_matmul_ds", "masked_matmul_grouped",
+                     "masked_matmul_grouped_dx",
+                     "masked_matmul_grouped_ds"}, calls
     ma = c.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
