@@ -265,11 +265,13 @@ def bench_shape(label, M, K, N, iters, warmup, key):
 
     bwd_ref = jax.jit(_bwd_ref)
 
-    # one cohort row of K*N scores: the per-round uplink sampling
-    flat = s.reshape(1, -1)
+    # one cohort's (1, K, N) score leaf, read in its own layout as the
+    # round's uplink reads it
+    leaf = s[None]
     seeds = jnp.asarray([7], jnp.uint32)
     sap = jax.jit(lambda f, sd: ops.sample_and_pack(f, sd))
-    sap_ref = jax.jit(lambda f, sd: ref.sample_and_pack(f, sd))
+    sap_ref = jax.jit(lambda f, sd: ref.sample_and_pack(
+        f.reshape(1, -1), sd))
 
     t = dict(
         fwd_us=timed(fwd, x, w, s, iters=iters, warmup=warmup),
@@ -277,9 +279,9 @@ def bench_shape(label, M, K, N, iters, warmup, key):
         bwd_us=timed(bwd, x, w, s, g, iters=iters, warmup=warmup),
         bwd_ref_us=timed(bwd_ref, x, w, s, g, iters=iters,
                          warmup=warmup),
-        sample_pack_us=timed(sap, flat, seeds, iters=iters,
+        sample_pack_us=timed(sap, leaf, seeds, iters=iters,
                              warmup=warmup),
-        sample_pack_ref_us=timed(sap_ref, flat, seeds, iters=iters,
+        sample_pack_ref_us=timed(sap_ref, leaf, seeds, iters=iters,
                                  warmup=warmup),
     )
     fwd_flops = 2 * M * K * N

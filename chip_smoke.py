@@ -19,8 +19,12 @@ One chip, in one process:
       the grouped kernels' plan (`ops.grouped_plan`) at the
       deepseek-v2-lite share cell's expert shapes: row block, static
       tile bound, live tiles at the mean load.
-  (b) `sample_and_pack` words of one 2048 x 8192 leaf at C=2 must EQUAL
-      the pure-jnp oracle `kernels.ref.sample_and_pack`.
+  (b) `sample_and_pack` words of one 2048 x 8192 leaf at C=2, read
+      flat (as rows of 4096) and at its stacked shape (C, 1, 2048,
+      8192) as the round reads it in place, must EQUAL the pure-jnp oracle
+      `kernels.ref.sample_and_pack`.  Prints `ops.pack_plan` for every
+      masked leaf of both benchmark configurations; every one must be
+      packed in place.
   (c) `ops.masked_dense` forward, dx and ds at that leaf against the jnp
       oracle under `default_matmul_precision("highest")`, each inside a
       tolerance derived below.
@@ -121,27 +125,59 @@ def phase_train(seed: int) -> dict:
     return out
 
 
+def _score_leaves(jax, arch, cohorts: int):
+    """(path, shape) of every masked score leaf of the federated state."""
+    from repro.core import masking
+    from repro.launch import steps as steplib
+    from repro.models import build_model
+    api = build_model(arch)
+    state = jax.eval_shape(lambda k: steplib.init_fed_state(
+        k, api, masking.MaskSpec(), C=cohorts), jax.random.PRNGKey(0))
+    return [(jax.tree_util.keystr(path), leaf.shape)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                state["scores"], is_leaf=lambda x: x is None)[0]
+            if leaf is not None]
+
+
 def projection_passes(jax) -> None:
     """How many times the forward and dx kernels stream each tile of `w`
     and `s` per call (`ops.dense_plan(...).passes`), for every masked
     projection of the train step at one cohort's BATCH x SEQ tokens."""
     from repro.configs import get_config
-    from repro.core import masking
     from repro.kernels import ops
-    from repro.launch import steps as steplib
-    from repro.models import build_model
-    api = build_model(get_config(ARCH, layers=LAYERS))
-    state = jax.eval_shape(lambda k: steplib.init_fed_state(
-        k, api, masking.MaskSpec(), C=COHORTS), jax.random.PRNGKey(0))
-    for path, leaf in jax.tree_util.tree_flatten_with_path(
-            state["scores"], is_leaf=lambda x: x is None)[0]:
-        if leaf is None:
-            continue
-        K, N = leaf.shape[-2:]
+    for path, shape in _score_leaves(jax, get_config(ARCH, layers=LAYERS),
+                                     COHORTS):
+        K, N = shape[-2:]
         plan = ops.dense_plan(BATCH * SEQ, K, N)
-        print(f"(a) {jax.tree_util.keystr(path)} ({K} x {N}) at "
-              f"M={BATCH * SEQ}: bm {plan.bm}, bn {plan.bn}, bk "
-              f"{plan.bk}, passes {plan.passes}")
+        print(f"(a) {path} ({K} x {N}) at M={BATCH * SEQ}: bm {plan.bm}, "
+              f"bn {plan.bn}, bk {plan.bk}, passes {plan.passes}")
+
+
+def pack_plans(jax) -> None:
+    """`ops.pack_plan` for every masked leaf of both benchmark
+    configurations (this cut at COHORTS cohorts; the deepseek-v2-lite
+    share at one: 5 layers, 8 experts held), and the share of masked
+    parameters whose scores the uplink reads in place."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.kernels import ops
+    share = dataclasses.replace(get_config("deepseek-v2-lite-16b"),
+                                n_layers=5, n_held_experts=8)
+    for name, arch, cohorts in ((ARCH, get_config(ARCH, layers=LAYERS),
+                                 COHORTS), ("deepseek-v2-lite-16b share",
+                                            share, 1)):
+        total = in_place = 0
+        for path, shape in _score_leaves(jax, arch, cohorts):
+            pack = ops.pack_plan(shape)
+            total += math.prod(shape)
+            in_place += math.prod(shape) * pack.in_place
+            print(f"(b) pack_plan {name} {path} {shape}: in_place "
+                  f"{pack.in_place}, br {pack.br}, grid_steps "
+                  f"{pack.grid_steps}")
+        print(f"(b) {name}: {in_place} of {total} masked parameters "
+              f"packed in place ({100 * in_place / total:g}%)")
+        require(in_place == total, f"{name}: a masked leaf is not packed "
+                                   "in place")
 
 
 def grouped_plans() -> None:
@@ -160,21 +196,26 @@ def grouped_plans() -> None:
 
 
 def phase_pack(jax, seed: int) -> None:
+    """The leaf's words, read flat and at its own stacked shape (the
+    round's in-place path), against the oracle of its flattened rows."""
     import jax.numpy as jnp
     from repro.kernels import ops, ref
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     s = jax.random.normal(k1, (COHORTS, LEAF_K * LEAF_N), jnp.float32)
     seeds = jax.random.bits(k2, (COHORTS,), jnp.uint32)
+    oracle = jax.jit(ref.sample_and_pack, static_argnames="mode")
     for mode in ("sample", "threshold"):
-        got = ops.sample_and_pack(s, seeds, mode=mode)
-        want = jax.jit(ref.sample_and_pack,
-                       static_argnames="mode")(s, seeds, mode=mode)
-        bad = int(jnp.sum(got != want))
-        print(f"(b) sample_and_pack {mode}: {got.shape[0]} x "
-              f"{got.shape[1]} words, {bad} differ from "
-              f"ref.sample_and_pack")
-        require(got.shape == want.shape and bad == 0,
-                f"sample_and_pack {mode} words differ from the oracle")
+        want = oracle(s, seeds, mode=mode)
+        for shape in ((COHORTS, LEAF_K * LEAF_N),
+                      (COHORTS, 1, LEAF_K, LEAF_N)):
+            got = ops.sample_and_pack(s.reshape(shape), seeds, mode=mode)
+            bad = int(jnp.sum(got != want))
+            print(f"(b) sample_and_pack {mode} at {shape} (in_place "
+                  f"{ops.pack_plan(shape).in_place}): {got.shape[0]} x "
+                  f"{got.shape[1]} words, {bad} differ from "
+                  f"ref.sample_and_pack")
+            require(got.shape == want.shape and bad == 0,
+                    f"sample_and_pack {mode} words differ from the oracle")
 
 
 def phase_dense(jax, seed: int) -> None:
@@ -267,6 +308,7 @@ def run_one_chip(jax, seed: int) -> None:
     del out["compiled_step"]
     projection_passes(jax)
     grouped_plans()
+    pack_plans(jax)
     phase_pack(jax, seed)
     phase_dense(jax, seed)
     phase_serve(seed)
@@ -374,8 +416,7 @@ def run_four_chips(jax, seed: int) -> None:
             seeds = steplib._mask_stream_seeds(
                 step_no, linear[d], i, sh.data.shape[0],
                 run_seed=scfg.seed, first=pos[d][0] * sh.data.shape[0])
-            kw = np.asarray(jax.jit(ops.sample_and_pack)(
-                sh.data.reshape(sh.data.shape[0], -1), seeds))
+            kw = np.asarray(jax.jit(ops.sample_and_pack)(sh.data, seeds))
             require(np.array_equal(kw, words[d]),
                     f"leaf {i} on {d}: kernel words differ from oracle")
     print(f"words: {n_words} per-shard words equal ref.sample_and_pack; "

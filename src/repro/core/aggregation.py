@@ -142,26 +142,28 @@ def aggregate_floats(float_trees: Sequence[Pytree],
                                   is_leaf=lambda x: x is None)
 
 
-def sample_and_pack_rows(flat_scores: jax.Array, seeds: jax.Array,
+def sample_and_pack_rows(scores: jax.Array, seeds: jax.Array,
                          use_kernel: bool = False, mode: str = "sample",
                          tau: float = 0.5) -> jax.Array:
     """Fused per-cohort uplink sampling + 32->1 bitpack.
 
-    (C, n) score rows + (C,) uint32 seeds -> (C, ceil(n/32)) uint32
-    words of m ~ Bern(sigmoid(scores)), where row c draws from the
-    counter-based hash stream seeded by seeds[c] (the same stream the
-    fused masked-matmul kernels regenerate).  With ``use_kernel`` the
-    one-pass Pallas kernel runs (scores -> hash -> Bernoulli -> words;
-    no uint8 mask in HBM); otherwise the pure-jnp two-pass reference —
-    the two are bit-identical.  ``mode="threshold"`` packs the
-    deterministic FedMask mask 1[sigmoid(s) > tau] (seeds ignored).
+    (C, *body) scores + (C,) uint32 seeds -> (C, ceil(n/32)) uint32
+    words of m ~ Bern(sigmoid(scores)) over each cohort's flattened
+    leaf of n elements, where row c draws from the counter-based hash
+    stream seeded by seeds[c] (the same stream the fused masked-matmul
+    kernels regenerate).  With ``use_kernel`` the one-pass Pallas
+    kernel runs (scores -> hash -> Bernoulli -> words; no uint8 mask in
+    HBM), reading a stacked leaf in its own layout where
+    `kernels.ops.pack_plan` says so; otherwise the pure-jnp two-pass
+    reference — the two are bit-identical.  ``mode="threshold"`` packs
+    the deterministic FedMask mask 1[sigmoid(s) > tau] (seeds ignored).
     """
     if use_kernel:
         from repro.kernels import ops as _kops
-        return _kops.sample_and_pack(flat_scores, seeds, mode=mode,
-                                     tau=tau)
+        return _kops.sample_and_pack(scores, seeds, mode=mode, tau=tau)
     from repro.kernels import ref as _kref
-    return _kref.sample_and_pack(flat_scores, seeds, mode=mode, tau=tau)
+    return _kref.sample_and_pack(scores.reshape(scores.shape[0], -1),
+                                 seeds, mode=mode, tau=tau)
 
 
 # ---------------------------------------------------------------------------

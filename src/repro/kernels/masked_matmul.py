@@ -23,7 +23,10 @@ and a fused uplink sampler —
 
   sample_and_pack:   scores -> hash -> Bernoulli -> packed uint32 words
                      in one pass (replaces sample-then-pack_bits, which
-                     materialized the full uint8 mask in HBM).
+                     materialized the full uint8 mask in HBM); it
+                     reads a leaf as (C, R, N) rows of whole words,
+                     in the leaf's own layout where it has such rows
+                     (`sample_and_pack_leaf`).
 
 The GROUPED family extends the same discipline to stacked (E, K, N)
 leaves (MoE expert weights): `masked_matmul_grouped` (+ dx/ds) runs one
@@ -371,73 +374,126 @@ def _or_lanes(m, axis: int):
     return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
-def _sap_kernel(s_ref, seed_ref, o_ref, *, bw: int, n_total: int,
-                mode: str, tau: float):
+# In place: a (C, R, N) leaf with N % 32 == 0 is read in its own lane-
+# dense layout.  The Bernoulli bits of a row are formed on full vregs,
+# and the MXU compacts each run of 32 lanes into a word: a {0,1} bf16
+# chunk times a constant power-of-two matrix gives the word's two 16-bit
+# halves, exact in f32 (each half-sum is below 2^16).  A group of 4096
+# lanes makes the 128 words of one output vreg row; in its matrix, word
+# w's low half lands in column w and its high half in column (w+64) %
+# 128, so the first 64 words take one pass of the MXU, and the last 64
+# a second pass whose halves a select and a 64-lane roll interleave.
+
+_PACK_GROUP = 4096       # lanes whose words fill 128 output lanes
+_PACK_CHUNK = 512        # lanes of one MXU pass (16 words)
+
+
+def _pack_matrix() -> jax.Array:
+    """(4096, 128) bf16: lane l of a group carries bit j = l % 32 of
+    word w = l // 32, scaled 2^(j % 16), in column w (j < 16) or
+    (w + 64) % 128 (j >= 16)."""
+    lane = jnp.arange(_PACK_GROUP)
+    w, j = lane // 32, lane % 32
+    col = jnp.where(j < 16, w, (w + 64) % 128)
+    val = jnp.left_shift(1, j % 16).astype(jnp.float32)
+    return (jax.nn.one_hot(col, 128, dtype=jnp.float32)
+            * val[:, None]).astype(jnp.bfloat16)
+
+
+def _sap_leaf_kernel(s_ref, seed_ref, p_ref, o_ref, *, br: int, rs: int,
+                     n_cols: int, n_valid: int | None, mode: str,
+                     tau: float):
     c, i = pl.program_id(0), pl.program_id(1)
-    # word/lane coordinates of this (bw, 32) tile; bit j of word wi
-    # carries flat element wi*32 + j (little-endian, matching pack_bits)
-    words = (i * bw).astype(jnp.uint32) + jax.lax.broadcasted_iota(
-        jnp.uint32, (bw, 32), 0)
-    lanes = jax.lax.broadcasted_iota(jnp.uint32, (bw, 32), 1)
-    idx = words * jnp.uint32(32) + lanes
+    seed = seed_ref[0, c]
+    half = jax.lax.broadcasted_iota(jnp.int32, (rs, 128), 1) < 64
 
-    theta = jax.nn.sigmoid(s_ref[...].astype(jnp.float32))
-    if mode == "threshold":
-        m = theta > jnp.float32(tau)
+    def slab(r0):
+        rows = (i * br + r0).astype(jnp.uint32) + jax.lax.broadcasted_iota(
+            jnp.uint32, (rs, 1), 0)
+        base = rows * jnp.uint32(n_cols)       # flat index of column 0
+        for g0 in range(0, n_cols, _PACK_GROUP):
+            width = min(_PACK_GROUP, n_cols - g0)
+            acc = [None, None]                 # words 0..63, 64..127
+            for c0 in range(0, width, _PACK_CHUNK):
+                cw = min(_PACK_CHUNK, width - c0)
+                theta = jax.nn.sigmoid(
+                    s_ref[pl.ds(r0, rs), pl.ds(g0 + c0, cw)].astype(
+                        jnp.float32))
+                idx = base + (jnp.uint32(g0 + c0) + jax.lax.broadcasted_iota(
+                    jnp.uint32, (1, cw), 1))
+                if mode == "threshold":
+                    m = theta > jnp.float32(tau)
+                else:
+                    m = _hash_uniform(idx, seed) < theta
+                if n_valid is not None:
+                    # the pad past a ragged leaf's n elements packs zeros,
+                    # as pack_bits of the words-padded mask does
+                    m = m & (idx < jnp.uint32(n_valid))
+                part = jnp.dot(m.astype(jnp.bfloat16),
+                               p_ref[pl.ds(c0, cw), :],
+                               preferred_element_type=jnp.float32)
+                h = c0 // (_PACK_GROUP // 2)
+                acc[h] = part if acc[h] is None else acc[h] + part
+            a, b = acc
+            if b is None:
+                lo, hi = a, pltpu.roll(a, 64, 1)
+            else:
+                lo = jnp.where(half, a, b)
+                hi = pltpu.roll(jnp.where(half, b, a), 64, 1)
+            words = jax.lax.bitcast_convert_type(
+                lo.astype(jnp.int32) | (hi.astype(jnp.int32) << 16),
+                jnp.uint32)
+            nw = width // 32
+            o_ref[pl.ds(r0, rs), pl.ds(g0 // 32, nw)] = words[:, :nw]
+
+    if br == rs:
+        # one slab: a static row offset, which Mosaic proves aligned
+        # for any rs (a traced one it cannot, for bf16 with rs odd)
+        slab(0)
     else:
-        m = _hash_uniform(idx, seed_ref[0, c]) < theta
-    # padding bits (idx >= n_total) are forced to zero so the packed
-    # words match pack_bits(pad_to_words(mask)) exactly
-    m = m & (idx < jnp.uint32(n_total))
-    o_ref[...] = _or_lanes(m, axis=1)[None, :]
+        def body(t, carry):
+            slab(pl.multiple_of(t * rs, rs))
+            return carry
+
+        jax.lax.fori_loop(0, br // rs, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("bw", "interpret", "mode",
-                                             "tau"))
-def sample_and_pack(s: jax.Array, seeds: jax.Array, *, bw: int = 512,
-                    interpret: bool = False, mode: str = "sample",
-                    tau: float = 0.5) -> jax.Array:
-    """s: (C, n) score rows; seeds: (C,) uint32 per-row stream seeds.
-    Returns (C, W) uint32 with W = ceil(n/32): the bit-packed Bernoulli
-    mask m = 1[hash_u(idx) < sigmoid(s)] of every row, sampled and
-    packed in one pass (bits past n are zero, as pad_to_words pads).
-    `mode="threshold"` packs the deterministic FedMask mask
-    m = 1[sigmoid(s) > tau] instead (seeds are ignored)."""
-    C, n = s.shape
-    assert seeds.shape == (C,), (seeds.shape, C)
-    W = (n + 31) // 32
-    # the word axis is the lane axis of the output block, so a block is
-    # either the whole row or a multiple of 128 words.  Real leaves (dims
-    # multiples of 128) give W divisible by the block and no pad copy;
-    # only a ragged long row is rounded up to whole blocks.
-    if W <= bw:
-        bw_, Wp = W, W
-    else:
-        bw_ = bw
-        while W % bw_ and bw_ > 128:
-            bw_ //= 2
-        if W % bw_:
-            bw_ = bw
-        Wp = -(-W // bw_) * bw_
-    pad = Wp * 32 - n
-    sp = jnp.pad(s, ((0, 0), (0, pad))) if pad else s
-    s3 = sp.reshape(C, Wp, 32)
-    kernel = functools.partial(_sap_kernel, bw=bw_, n_total=n,
-                               mode=mode, tau=tau)
-    out = pl.pallas_call(
+@functools.partial(jax.jit, static_argnames=("br", "n", "interpret",
+                                             "mode", "tau"))
+def sample_and_pack_leaf(s: jax.Array, seeds: jax.Array, *, br: int,
+                         n: int | None = None, interpret: bool = False,
+                         mode: str = "sample",
+                         tau: float = 0.5) -> jax.Array:
+    """s: (C, R, N) score rows of a leaf, N % 32 == 0; seeds: (C,).
+    Returns (C, R, N // 32) uint32: word w of row r packs flat elements
+    (r*N + 32w) .. +31 of cohort c's row, little-endian, so its reshape
+    to (C, R*N // 32) is `sample_and_pack` of the flattened leaf.  With
+    `n` < R*N the rows are a leaf of n elements padded to whole rows,
+    and the bits past n are zero.  The grid reads (br, N) blocks, br a
+    multiple of 16 or the whole R; the kernel walks a block 64, 32 or
+    16 rows at a time, the most that divide it (all of it where none
+    does)."""
+    C, R, N = s.shape
+    assert N % 32 == 0 and seeds.shape == (C,), (s.shape, seeds.shape)
+    rs = next((r for r in (64, 32, 16) if br % r == 0), br)
+    assert br == R or rs % 16 == 0, (br, R)
+    n_valid = n if n is not None and n < R * N else None
+    kernel = functools.partial(_sap_leaf_kernel, br=br, rs=rs, n_cols=N,
+                               n_valid=n_valid, mode=mode, tau=tau)
+    return pl.pallas_call(
         kernel,
         name="sample_and_pack",
-        grid=(C, Wp // bw_),
+        grid=(C, pl.cdiv(R, br)),
         in_specs=[
-            pl.BlockSpec((None, bw_, 32), lambda c, i: (c, i, 0)),
+            pl.BlockSpec((None, br, N), lambda c, i: (c, i, 0)),
             _SCALAR_SPEC,
+            pl.BlockSpec((_PACK_GROUP, 128), lambda c, i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, 1, bw_), lambda c, i: (c, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((C, 1, Wp), jnp.uint32),
+        out_specs=pl.BlockSpec((None, br, N // 32), lambda c, i: (c, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((C, R, N // 32), jnp.uint32),
+        compiler_params=_DENSE_PARAMS,
         interpret=interpret,
-    )(s3, jnp.asarray(seeds, jnp.uint32).reshape(1, C))
-    out = out.reshape(C, Wp)
-    return out[:, :W]
+    )(s, jnp.asarray(seeds, jnp.uint32).reshape(1, C), _pack_matrix())
 
 
 # ---------------------------------------------------------------------------

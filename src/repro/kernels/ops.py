@@ -55,6 +55,7 @@ the kernels cannot compile for fails loudly instead of being emulated.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import NamedTuple
 
@@ -103,18 +104,79 @@ def unpack_bits(words: jax.Array, n: int) -> jax.Array:
     return _bp.unpack_bits(words, n, interpret=_use_interpret())
 
 
+class PackPlan(NamedTuple):
+    """How `sample_and_pack` reads a (C, *body) leaf: as (C, rows, cols)
+    score rows, `br` rows a block, in a grid of `grid_steps`.
+    `in_place`: those rows are the leaf's own layout, so no relayout of
+    the scores runs in front of the kernel; else the leaf is copied
+    into them (flattened, and padded to whole rows where it must be)."""
+    in_place: bool
+    br: int
+    grid_steps: int
+    rows: int
+    cols: int
+
+
+_PACK_BLOCK_BYTES = 2 * 2**20    # f32 score bytes of one block
+_PACK_FLAT_COLS = 4096           # row width of a leaf without word rows
+
+
+def pack_plan(shape, dtype=jnp.float32) -> PackPlan:
+    """The rows `sample_and_pack` reads a leaf of `shape` (C, *body) and
+    `dtype` as, from the shape alone.  A body of two or more dims whose
+    minor dim N is a multiple of 32 gives rows of N, R = prod(body[:-1]),
+    so each row's words are whole.  They are the leaf's own layout when
+    collapsing the body's leading dims moves nothing: a body of two
+    dims, or a second-minor dim of whole sublane tiles (8 rows of f32,
+    16 of bf16; every masked leaf of the benchmark's configurations).
+    Any other leaf is flattened and padded into rows of up to 4096.
+    br is the whole R where its f32 block stays within 2 MiB (and R is
+    at most 64 rows or a multiple of 16), else the most rows, in
+    multiples of 16, that divide R and stay within it (where none
+    divides, the most that stay within it and R, and a partial last
+    block)."""
+    C, body = shape[0], tuple(shape[1:])
+    n = math.prod(body)
+    if len(body) >= 2 and body[-1] % 32 == 0:
+        cols = body[-1]
+        sublanes = 32 // jnp.dtype(dtype).itemsize
+        in_place = len(body) == 2 or body[-2] % sublanes == 0
+    else:
+        cols, in_place = min(_PACK_FLAT_COLS, -(-n // 512) * 512), False
+    rows = -(-n // cols)
+    cap = max(16, _PACK_BLOCK_BYTES // (4 * cols) // 16 * 16)
+    if rows <= cap and (rows <= 64 or rows % 16 == 0):
+        br = rows
+    else:
+        top = min(cap, rows) // 16 * 16
+        br = next((b for b in range(top, 0, -16) if rows % b == 0), top)
+    return PackPlan(in_place, br, C * -(-rows // br), rows, cols)
+
+
 def sample_and_pack(scores: jax.Array, seeds: jax.Array,
                     mode: str = "sample", tau: float = 0.5) -> jax.Array:
-    """Fused uplink sampler: (C, n) score rows + (C,) uint32 seeds ->
-    (C, ceil(n/32)) uint32 words of m ~ Bern(sigmoid(scores)).
+    """Fused uplink sampler: (C, *body) scores + (C,) uint32 seeds ->
+    (C, ceil(n/32)) uint32 words of m ~ Bern(sigmoid(scores)) over each
+    cohort's flattened leaf of n elements.
 
     One kernel pass replaces the sample-then-pack_bits two-pass; the
-    full uint8 mask never exists in HBM.  `ref.sample_rows` /
-    `ref.sample_and_pack` are the bit-exact jnp oracles.
-    `mode="threshold"` packs m = 1[sigmoid(scores) > tau] (FedMask).
+    full uint8 mask never exists in HBM.  The kernel
+    (`masked_matmul.sample_and_pack_leaf`) reads the rows `pack_plan`
+    gives: the leaf's own layout where it has rows of whole words.
+    `ref.sample_rows` / `ref.sample_and_pack` are the bit-exact jnp
+    oracles of the flattened leaf.  `mode="threshold"` packs
+    m = 1[sigmoid(scores) > tau] (FedMask).
     """
-    return _mm.sample_and_pack(scores, seeds, interpret=_use_interpret(),
-                               mode=mode, tau=tau)
+    C = scores.shape[0]
+    n = math.prod(scores.shape[1:])
+    plan = pack_plan(scores.shape, scores.dtype)
+    pad = plan.rows * plan.cols - n
+    if pad:
+        scores = jnp.pad(scores.reshape(C, n), ((0, 0), (0, pad)))
+    words = _mm.sample_and_pack_leaf(
+        scores.reshape(C, plan.rows, plan.cols), seeds, br=plan.br, n=n,
+        interpret=_use_interpret(), mode=mode, tau=tau)
+    return words.reshape(C, -1)[:, :-(-n // 32)]
 
 
 # ---------------------------------------------------------------------------

@@ -233,6 +233,18 @@ def sample_and_pack(s2, seeds, mode="sample", tau=0.5):
     return jax.vmap(pack_bits)(m)
 
 
+def sample_and_pack_leaf(s3, seeds, mode="sample", tau=0.5, n=None):
+    """Oracle for kernels.sample_and_pack_leaf: (C, R, N) scores, N % 32
+    == 0 -> (C, R, N // 32) words, the flattened rows' words by row;
+    with `n`, those of the first n elements, zero past them."""
+    C, R, N = s3.shape
+    n = R * N if n is None else n
+    words = sample_and_pack(s3.reshape(C, R * N)[:, :n], seeds, mode=mode,
+                            tau=tau)
+    words = jnp.pad(words, ((0, 0), (0, R * N // 32 - words.shape[1])))
+    return words.reshape(C, R, N // 32)
+
+
 def pack_bits(mask_flat):
     bits = mask_flat.astype(jnp.uint32).reshape(-1, 32)
     shifts = jnp.arange(32, dtype=jnp.uint32)

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import os
 from typing import Any, Optional
 
@@ -436,13 +437,14 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
             Cl = Cl_any = sl.shape[0]
             body = sl.shape[1:]
             with jax.named_scope("uplink"):
-                flat = sl.reshape(Cl, -1)
-                n = flat.shape[1]
+                n = math.prod(body)
                 seeds = _mask_stream_seeds(step, dev, i, Cl,
                                            run_seed=cfg.seed, first=first)
                 if cfg.packed_masks:
+                    # the leaf as the state stores it: the kernel reads
+                    # its rows in place (`kernels.ops.pack_plan`)
                     words = aggregation.sample_and_pack_rows(
-                        flat, seeds, use_kernel=True,
+                        sl, seeds, use_kernel=True,
                         mode=cfg.mask_mode, tau=cfg.tau)   # (Cl, W) u32
                     ones_parts.append(jnp.sum(
                         jax.lax.population_count(words),
@@ -454,6 +456,7 @@ def make_round_step(api, cfg: StepConfig, mesh=None, state_sh=None,
                             lambda wd: aggregation.unpack_bits(wd, n)
                         )(words))
                 else:
+                    flat = sl.reshape(Cl, -1)
                     masks2 = (kref.threshold_rows(flat, cfg.tau)
                               if cfg.mask_mode == "threshold"
                               else kref.sample_rows(flat, seeds))

@@ -15,7 +15,7 @@ from repro.kernels.masked_matmul import (masked_matmul, masked_matmul_dx,
                                          masked_matmul_grouped,
                                          masked_matmul_grouped_dx,
                                          masked_matmul_grouped_ds,
-                                         sample_and_pack, VMEM_BUDGET)
+                                         sample_and_pack_leaf, VMEM_BUDGET)
 from repro.kernels.bitpack import pack_bits, unpack_bits
 
 
@@ -287,11 +287,12 @@ def test_padded_launch_mask_matches_ref_bit_exact():
 ])
 def test_sample_and_pack_matches_ref(seed, C, n):
     """Fixed-seed fallback for the hypothesis sweep: the fused kernel's
-    words equal the two-pass sample-then-pack oracle exactly."""
+    words of flat (C, n) rows equal the two-pass sample-then-pack oracle
+    exactly."""
     key = jax.random.PRNGKey(seed)
     s = jax.random.normal(key, (C, n), jnp.float32)
     seeds = jnp.arange(C, dtype=jnp.uint32) * 7919 + seed
-    words = sample_and_pack(s, seeds, interpret=True)
+    words = ops.sample_and_pack(s, seeds)
     words_ref = ref.sample_and_pack(s, seeds)
     assert words.shape == (C, (n + 31) // 32)
     assert bool(jnp.all(words == words_ref))
@@ -305,10 +306,95 @@ def test_sample_and_pack_extreme_scores():
     s_on = jnp.full((1, n), 40.0)
     s_off = jnp.full((1, n), -40.0)
     seeds = jnp.asarray([5], jnp.uint32)
-    assert bool(jnp.all(sample_and_pack(s_on, seeds, interpret=True)
+    assert bool(jnp.all(ops.sample_and_pack(s_on, seeds)
                         == jnp.uint32(0xFFFFFFFF)))
-    assert bool(jnp.all(sample_and_pack(s_off, seeds, interpret=True)
-                        == 0))
+    assert bool(jnp.all(ops.sample_and_pack(s_off, seeds) == 0))
+
+
+@pytest.mark.parametrize("mode", ["sample", "threshold"])
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("body,in_place", [
+    ((2, 256, 128), True), ((1, 128, 576), True), ((3, 64, 1408), True),
+    ((2, 2, 64, 256), True),    # an expert stack (L, E, K, N)
+    ((2, 40, 33), False),       # rows of ragged words: padded rows
+])
+@pytest.mark.parametrize("C", [1, 2, 4])
+def test_sample_and_pack_leaf_matches_ref(C, body, in_place, dtype, mode):
+    """`ops.sample_and_pack` of a stacked (C, *body) leaf equals the
+    two-pass oracle on the flattened rows bit for bit, in place or by
+    the flat fallback as `ops.pack_plan` says, with +-40 scores in the
+    leaf's first and last rows."""
+    s = jax.random.normal(jax.random.PRNGKey(C + sum(body)),
+                          (C,) + body, jnp.float32) * 3
+    s = s.reshape(C, -1, body[-1]).at[:, 0].set(40.0).at[:, -1].set(-40.0)
+    s = s.reshape((C,) + body).astype(dtype)
+    seeds = jnp.arange(C, dtype=jnp.uint32) * 104729 + jnp.uint32(2**31 + 5)
+    plan = ops.pack_plan(s.shape, s.dtype)
+    assert plan.in_place is in_place
+    assert plan.grid_steps >= C
+    words = ops.sample_and_pack(s, seeds, mode=mode, tau=0.3)
+    want = ref.sample_and_pack(s.reshape(C, -1), seeds, mode=mode, tau=0.3)
+    assert words.shape == want.shape
+    assert np.array_equal(np.asarray(words), np.asarray(want))
+
+
+@pytest.mark.parametrize("shape,br", [
+    ((2, 48, 64), 16),       # three whole blocks, 16 rows a slab
+    ((2, 40, 64), 16),       # a partial last block of 8 rows
+    ((1, 8, 4160), 8),       # two 4096-lane groups, the second of 64
+])
+@pytest.mark.parametrize("mode", ["sample", "threshold"])
+def test_sample_and_pack_leaf_kernel_matches_leaf_oracle(shape, br, mode):
+    """The in-place kernel at a given row block equals its oracle
+    `ref.sample_and_pack_leaf` word for word, over several blocks, a
+    ragged last block and a row wider than one packing group."""
+    s = jax.random.normal(jax.random.PRNGKey(shape[1]), shape) * 3
+    seeds = jnp.arange(shape[0], dtype=jnp.uint32) + jnp.uint32(2**31 - 9)
+    words = sample_and_pack_leaf(s, seeds, br=br, interpret=True, mode=mode,
+                                 tau=0.6)
+    want = ref.sample_and_pack_leaf(s, seeds, mode=mode, tau=0.6)
+    assert words.shape == want.shape == shape[:2] + (shape[2] // 32,)
+    assert np.array_equal(np.asarray(words), np.asarray(want))
+
+
+@pytest.mark.parametrize("mode", ["sample", "threshold"])
+def test_sample_and_pack_leaf_kernel_zeroes_the_pad(mode):
+    """Rows that pad a leaf of n elements: the bits past n are zero
+    even where the pad's scores would set them, and the words before
+    them equal the oracle's."""
+    n = 150
+    s = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 64)) * 3
+    s = s.reshape(2, -1).at[:, n:].set(40.0).reshape(2, 3, 64)
+    seeds = jnp.asarray([7, 2**31 + 3], jnp.uint32)
+    words = sample_and_pack_leaf(s, seeds, br=3, n=n, interpret=True,
+                                 mode=mode, tau=0.6)
+    want = ref.sample_and_pack_leaf(s, seeds, mode=mode, tau=0.6, n=n)
+    assert np.array_equal(np.asarray(words), np.asarray(want))
+    flat = np.asarray(words).reshape(2, -1)
+    assert not (flat[:, n // 32] >> (n % 32)).any()
+    assert not flat[:, -(-n // 32):].any()
+
+
+@pytest.mark.parametrize("shape,dtype,in_place,rows,cols", [
+    ((2, 2048, 8192), jnp.float32, True, 2048, 8192),     # (C, K, N)
+    ((2, 4, 2048, 576), jnp.float32, True, 8192, 576),    # a layer stack
+    ((2, 4, 8, 64), jnp.float32, True, 32, 64),
+    ((2, 4, 8, 64), jnp.bfloat16, False, 32, 64),         # 8 of 16 rows
+    ((2, 24, 4, 2048), jnp.float32, False, 96, 2048),     # conv (L, 4, C)
+    ((2, 40, 33), jnp.float32, False, 1, 1536),           # ragged words
+    ((3, 1000), jnp.float32, False, 1, 1024),             # flat rows
+    ((2, 2048 * 8192), jnp.float32, False, 4096, 4096),
+])
+def test_pack_plan_in_place_only_where_rows_are_free(shape, dtype, in_place,
+                                                     rows, cols):
+    """`ops.pack_plan` reads a leaf in its own layout only where its
+    rows need no relayout; any other leaf is viewed as rows of whole
+    words, padded, and still packs to the flattened leaf's words."""
+    plan = ops.pack_plan(shape, dtype)
+    assert (plan.in_place, plan.rows, plan.cols) == (in_place, rows, cols)
+    assert plan.rows * plan.cols >= np.prod(shape[1:]) and plan.cols % 32 == 0
+    assert plan.br == plan.rows or plan.br % 16 == 0
+    assert plan.grid_steps == shape[0] * -(-plan.rows // plan.br)
 
 
 @pytest.mark.parametrize("shape", [(32, 64, 16), (40, 100, 60),
@@ -423,8 +509,7 @@ def test_masked_dense_threshold_forward_and_grads():
 def test_sample_and_pack_threshold_mode():
     s2 = jax.random.normal(jax.random.PRNGKey(5), (2, 500), jnp.float32)
     seeds = jnp.asarray([1, 2], jnp.uint32)
-    wt = sample_and_pack(s2, seeds, interpret=True, mode="threshold",
-                         tau=0.3)
+    wt = ops.sample_and_pack(s2, seeds, mode="threshold", tau=0.3)
     wr = ref.sample_and_pack(s2, seeds, mode="threshold", tau=0.3)
     assert np.array_equal(np.asarray(wt), np.asarray(wr))
     m = jax.vmap(lambda wd: ref.unpack_bits(wd, 500))(wt)

@@ -15,8 +15,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.bitpack import pack_bits, unpack_bits
-from repro.kernels.masked_matmul import (masked_matmul, masked_matmul_dx,
-                                         sample_and_pack)
+from repro.kernels.masked_matmul import masked_matmul, masked_matmul_dx
 
 
 @given(st.integers(0, 2 ** 20), st.integers(1, 64))
@@ -62,8 +61,33 @@ def test_sample_and_pack_matches_two_pass_property(seed, C, n):
     key = jax.random.PRNGKey(seed % 9973)
     s = jax.random.normal(key, (C, n), jnp.float32)
     seeds = jnp.arange(C, dtype=jnp.uint32) * 104729 + seed
-    words = sample_and_pack(s, seeds, interpret=True)
+    words = ops.sample_and_pack(s, seeds)
     assert bool(jnp.all(words == ref.sample_and_pack(s, seeds)))
+
+
+@given(st.integers(0, 2 ** 32 - 1), st.integers(1, 3),
+       st.lists(st.integers(1, 3), min_size=0, max_size=2),
+       st.integers(1, 70), st.integers(1, 160),
+       st.sampled_from(["float32", "bfloat16"]),
+       st.sampled_from(["sample", "threshold"]))
+@settings(max_examples=15, deadline=None)
+def test_sample_and_pack_stacked_leaf_property(seed, C, lead, R, words,
+                                               dtype, mode):
+    """A stacked (C, *lead, R, N) leaf packs to the two-pass oracle's
+    words of its flattened rows, for any stack, row count and width:
+    in place where N is whole words and the stack's rows need no
+    relayout, through padded rows elsewhere."""
+    N = 32 * words if seed % 3 else 32 * words + seed % 31 + 1
+    shape = (C, *lead, R, N)
+    s = (jax.random.normal(jax.random.PRNGKey(seed % 9973), shape,
+                           jnp.float32) * 4).astype(dtype)
+    seeds = jnp.arange(C, dtype=jnp.uint32) * 104729 + jnp.uint32(seed)
+    sublanes = 8 if dtype == "float32" else 16
+    assert ops.pack_plan(shape, s.dtype).in_place == (
+        N % 32 == 0 and (not lead or R % sublanes == 0))
+    words_ = ops.sample_and_pack(s, seeds, mode=mode, tau=0.4)
+    want = ref.sample_and_pack(s.reshape(C, -1), seeds, mode=mode, tau=0.4)
+    assert np.array_equal(np.asarray(words_), np.asarray(want))
 
 
 @given(st.integers(0, 2 ** 16),

@@ -135,6 +135,48 @@ def test_sample_and_pack_rows_kernel_matches_reference():
     assert not bool(jnp.all(wk[0] == wk[1]))
 
 
+def test_round_step_packs_stacked_leaves_as_reference(monkeypatch):
+    """The round step hands each score leaf to the uplink as the state
+    stores it, (C, L, K, N), and the kernel packs it in place; its words
+    and the new theta equal those of the `use_kernel=False` reference
+    path bit for bit."""
+    from repro.core import aggregation
+    from repro.kernels import ops
+    cfg, api = _mini()
+    key = jax.random.PRNGKey(21)
+    state = steplib.init_fed_state(key, api, SPEC, C=2)
+    state["scores"] = jax.tree_util.tree_map(
+        lambda s: None if s is None else s
+        + 2 * jax.random.normal(key, s.shape),
+        state["scores"], is_leaf=lambda x: x is None)
+    leaves = [leaf for _, leaf in masking.leaves_with_paths(state["scores"])
+              if leaf is not None]
+    real = aggregation.sample_and_pack_rows
+
+    def run(use_kernel):
+        seen = []
+
+        def spy(scores, seeds, use_kernel=False, **kw):
+            words = real(scores, seeds, use_kernel=spy.kernel, **kw)
+            seen.append((scores.shape, words))
+            return words
+
+        spy.kernel = use_kernel
+        monkeypatch.setattr(aggregation, "sample_and_pack_rows", spy)
+        new_state, _ = steplib.make_round_step(api, steplib.StepConfig(
+            packed_masks=True))(state)
+        return seen, new_state["scores"]
+
+    seen_k, theta_k = run(True)
+    seen_r, theta_r = run(False)
+    assert sorted(shape for shape, _ in seen_k) == sorted(
+        leaf.shape for leaf in leaves)
+    assert all(ops.pack_plan(shape).in_place for shape, _ in seen_k)
+    for (_, wk), (_, wr) in zip(seen_k, seen_r):
+        assert np.array_equal(np.asarray(wk), np.asarray(wr))
+    assert _scores_equal(theta_k, theta_r)
+
+
 def _scores_equal(a, b):
     return all(
         x is None or np.array_equal(np.asarray(x), np.asarray(y))

@@ -11,9 +11,11 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import functools
 import pathlib
 import re
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -106,11 +108,49 @@ def test_masked_matmul_compiles(one_chip, kernel, leaf):
 
 
 @pytest.mark.parametrize("mode", ["sample", "threshold"])
-def test_sample_and_pack_compiles(one_chip, mode):
-    c = _compile(lambda s, sd: _mm.sample_and_pack(
-        s, sd, mode=mode, interpret=False), one_chip,
-        ((2, D * F), f32), ((2,), u32))
+def test_sample_and_pack_compiles(one_chip, monkeypatch, mode):
+    """Flat (C, n) rows, read as padded rows of whole words."""
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    c = _compile(lambda s, sd: ops.sample_and_pack(s, sd, mode=mode),
+                 one_chip, ((2, D * F), f32), ((2,), u32))
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("shape", [
+    (2, 1000),             # flat: one padded row a cohort
+    (2, 40, 33),           # ragged words: flattened, one padded row
+    (2, 33, 64),           # in place, an odd number of rows
+])
+@pytest.mark.parametrize("dtype", [f32, bf], ids=["f32", "bf16"])
+def test_sample_and_pack_single_slab_compiles(one_chip, monkeypatch,
+                                              shape, dtype):
+    """Row blocks walked in one slab, of any row count: bf16 scores
+    pack two rows a sublane, so the slab's row offset must be one
+    Mosaic can prove even."""
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    c = _compile(lambda s, sd: ops.sample_and_pack(s, sd), one_chip,
+                 (shape, dtype), ((shape[0],), u32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("shape", [
+    (2, 4, D, F),          # internlm2-1.8b MLP up: 2 cohorts x 4 layers
+    (1, 1, DE, 576),       # deepseek-v2-lite w_dkv (N not 128-aligned)
+    (1, 4, EH, DE, FE),    # deepseek-v2-lite held experts' up stack
+])
+@pytest.mark.parametrize("mode", ["sample", "threshold"])
+def test_sample_and_pack_in_place_compiles(one_chip, monkeypatch, shape,
+                                           mode):
+    """The uplink's in-place path at the cells' leaf shapes: the
+    scores are read as (C, R, N) blocks with no relayout in front of
+    the kernel."""
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    assert ops.pack_plan(shape).in_place
+    c = _compile(lambda s, sd: ops.sample_and_pack(s, sd, mode=mode),
+                 one_chip, (shape, f32), ((shape[0],), u32))
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.findall(r"= f32\[[0-9,]*,32\]", text)
 
 
 @pytest.mark.parametrize("kernel", ["pack", "unpack"])
@@ -156,19 +196,15 @@ def test_grouped_compiles(one_chip, kernel, proj):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("step", ["train", "round"])
-def test_step_compiles_and_fits(one_chip, monkeypatch, step):
+@functools.lru_cache(maxsize=None)
+def _step_compiled(one_chip, step):
     """The whole jitted step of `chip_smoke.py`'s cut (4 layers, 2
-    cohorts, batch 2 x seq 512, state donated) compiles for one v5e,
-    holds its kernels under their fixed names, and fits the chip's 16
-    GiB."""
+    cohorts, batch 2 x seq 512, state donated), compiled for one v5e
+    once per test process."""
     from repro.configs import get_config
     from repro.core import masking
     from repro.launch import steps as steplib
     from repro.models import build_model
-    # the kernels are compiled for the chip, not interpreted as this
-    # CPU backend would choose
-    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
     api = build_model(get_config("internlm2-1.8b", layers=4))
     scfg = steplib.StepConfig(lr=0.3, downlink_bits=8)
     state = jax.tree_util.tree_map(
@@ -183,7 +219,19 @@ def test_step_compiles_and_fits(one_chip, monkeypatch, step):
     else:
         fn = steplib.make_round_step(api, scfg, codec="arithmetic")
         args = (state,)
-    c = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    # the kernels are compiled for the chip, not interpreted as this
+    # CPU backend would choose
+    with mock.patch.object(ops, "_use_interpret", lambda: False):
+        return jax.jit(fn, donate_argnums=0).lower(*args).compile()
+
+
+@pytest.mark.parametrize("step", ["train", "round"])
+def test_step_compiles_and_fits(one_chip, step):
+    """The whole jitted step of `chip_smoke.py`'s cut (4 layers, 2
+    cohorts, batch 2 x seq 512, state donated) compiles for one v5e,
+    holds its kernels under their fixed names, and fits the chip's 16
+    GiB."""
+    c = _step_compiled(one_chip, step)
     text = c.as_text()
     assert "tpu_custom_call" in text
     # the kernels' calls are named by `pallas_call(name=...)`, whatever
@@ -202,6 +250,21 @@ def test_step_compiles_and_fits(one_chip, monkeypatch, step):
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert live < HBM_BYTES, live
+
+
+def test_round_uplink_reads_scores_in_place(one_chip):
+    """The round step's uplink at the cut's real widths holds the
+    `sample_and_pack` kernel and no f32 array whose minor dim is 32:
+    the scores reach the kernel in the layout the state stores them
+    in, with no (words, 32) relayout in front of it."""
+    text = _step_compiled(one_chip, "round").as_text()
+    uplink = [ln for ln in text.splitlines()
+              if "jit(round_step)/uplink/" in ln]
+    assert any(re.search(r"%sample_and_pack\S* = u32\[", ln)
+               and 'custom_call_target="tpu_custom_call"' in ln
+               for ln in uplink), "no sample_and_pack call in the uplink"
+    assert not [ln for ln in uplink
+                if re.search(r"= f32\[[0-9,]*,32\]", ln)]
 
 
 def test_moe_share_train_step_compiles_and_fits(one_chip, monkeypatch):
